@@ -24,7 +24,7 @@ geometries (the interleaved half-strip plan).  ``--pool`` times the
 event-native max-pool (segment max over stream events, one launch) against
 the dense pool + re-encode round-trip.  ``--serve`` benchmarks the bucketed
 AOT-warmed serving replica (``repro.serving``): requests/s and p50/p99 per
-batch bucket, cold vs persistent-cache-warmed compile, and replica
+batch bucket, cold compile vs snapshot restore, and replica
 time-to-first-response.  ``--sweep`` runs the occupancy sweep 0→1 over
 conv/pool/linear boundaries: every route timed per point (``crossover``
 entries — the calibrated table ``route="adaptive"`` dispatch consults,
@@ -74,6 +74,7 @@ def _timeit(fn, *args, reps=3, **kw):
 
 def rows(reps=3):
     rng = np.random.default_rng(0)
+    interp = engine.EngineConfig().resolve_interpret()
     out = []
     for sparsity in (0.0, 0.7, 0.95):
         m, k, n = 64, 1024, 512
@@ -81,7 +82,7 @@ def rows(reps=3):
         a *= rng.random((m, k)) > sparsity
         w = rng.normal(size=(k, n)).astype(np.float32)
         us, cus, y = _timeit(event_matmul, jnp.asarray(a), jnp.asarray(w),
-                             blk_m=8, blk_k=128, interpret=True, reps=reps)
+                             blk_m=8, blk_k=128, interpret=interp, reps=reps)
         yr = event_matmul_ref(jnp.asarray(a), jnp.asarray(w), blk_m=8,
                               blk_k=128)
         live = np.abs(a.reshape(8, 8, 8, 128)).max(axis=(1, 3)) > 0
@@ -90,7 +91,7 @@ def rows(reps=3):
                     f"allclose={np.allclose(y, yr, atol=1e-4)}"))
     acc = jnp.asarray(rng.normal(size=(128, 1024)), jnp.float32)
     us, cus, (f, occ) = _timeit(fire_compact, acc, blk_m=8, blk_k=128,
-                                interpret=True, reps=reps)
+                                interpret=interp, reps=reps)
     fr, occr = fire_compact_ref(acc, blk_m=8, blk_k=128)
     out.append(("fire_compact", us, cus,
                 f"allclose={np.allclose(f, fr)};"
@@ -101,7 +102,7 @@ def rows(reps=3):
     w6 = jnp.asarray(rng.uniform(0.3, 0.99, (b, h, t, d)), jnp.float32)
     u = jnp.asarray(rng.normal(size=(h, d)), jnp.float32)
     us, cus, (o, s) = _timeit(wkv6, r, k2, v, w6, u, chunk=16,
-                              interpret=True, reps=reps)
+                              interpret=interp, reps=reps)
     orf, srf = jax.vmap(wkv6_ref, in_axes=(1, 1, 1, 1, 0),
                         out_axes=(1, 1))(r, k2, v, w6, u)
     out.append(("wkv6_chunked", us, cus,
@@ -112,12 +113,14 @@ def rows(reps=3):
 
 def _merge_bench(out_path: str, entries, drop_kinds: set):
     """Read-modify-write BENCH_engine.json: each mode owns its entry kinds."""
-    payload = dict(device=jax.default_backend(),
-                   note="CPU interpret-mode wall-times; structural columns "
-                        "(allclose, events, bit_exact, boundaries) are what "
-                        "transfers; compile_us is trace+compile, us is "
-                        "steady-state",
-                   entries=[])
+    dev = jax.devices()[0]
+    payload = dict(platform=dev.platform, device_kind=dev.device_kind,
+                   device_count=len(jax.devices()), entries=[])
+    if dev.platform != "tpu":
+        payload["note"] = ("CPU interpret-mode wall-times; structural "
+                           "columns (allclose, events, bit_exact, "
+                           "boundaries) are what transfers; compile_us is "
+                           "trace+compile, us is steady-state")
     if os.path.exists(out_path):
         try:
             with open(out_path) as f:
@@ -651,8 +654,8 @@ def serve_rows(out_path: str = "BENCH_engine.json", *, smoke=False, reps=3):
     first with an empty cache (cold — every bucket pays a real trace +
     lower + XLA compile) and the second re-warming from disk (warm — the
     restarted-replica path: per-bucket executable snapshots restore
-    finished executables with no trace/lower/compile at all, the
-    persistent compilation cache covering any snapshot miss).  Per bucket: steady-state requests/s and p50/p99
+    finished executables with no trace/lower/compile at all).  Per
+    bucket: steady-state requests/s and p50/p99
     latency through the full submit → route → pad → execute → unpad path,
     cold vs warmed compile time, and the bitwise padding check (one real
     row padded up to the bucket == the unpadded bucket-1 forward).  The
@@ -681,7 +684,10 @@ def serve_rows(out_path: str = "BENCH_engine.json", *, smoke=False, reps=3):
         (max(buckets), spec.input_size, spec.input_size, spec.in_ch),
         dtype=np.float32), 0.0)
     img = images[0]
-    cache_dir = tempfile.mkdtemp(prefix="mnf_serve_bench_")
+    # Snapshots go to a fresh directory, so the cold replica finds none
+    # (and, with a cache_dir, compiles around the XLA compile cache).
+    snapshots = tempfile.TemporaryDirectory(prefix="mnf_serve_bench_")
+    cache_dir = snapshots.name
 
     def replica():
         """Fresh replica against the shared cache, warming progressively:
@@ -1429,7 +1435,7 @@ def main():
     ap.add_argument("--serve", action="store_true",
                     help="benchmark the bucketed AOT-warmed serving "
                          "replica: requests/s + p50/p99 per bucket, cold "
-                         "vs persistent-cache-warmed compile and replica "
+                         "compile vs snapshot restore and replica "
                          "TTFR (serve_bench entries)")
     ap.add_argument("--mlp", action="store_true",
                     help="benchmark the event-native MLP chain (mlp_chain "

@@ -33,8 +33,8 @@ def main():
     ap.add_argument("--buckets", default="1,4,8",
                     help="compiled batch bucket sizes, ascending")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent compilation cache (restarted replicas "
-                         "re-warm from disk)")
+                    help="executable snapshot directory (restarted "
+                         "replicas re-warm from disk)")
     ap.add_argument("--weight-sparsity", type=float, default=0.5)
     ap.add_argument("--act-sparsity", type=float, default=0.6)
     args = ap.parse_args()

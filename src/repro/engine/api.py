@@ -19,6 +19,9 @@ from repro.engine import trace
 from repro.engine.config import EngineConfig
 from repro.engine.registry import dispatch, get_backend, list_backends
 from repro.engine.stream import EventStream
+from repro.kernels.event_conv.kernel import event_conv_launches
+from repro.kernels.event_matmul.kernel import event_matmul_launches
+from repro.kernels.event_pool.kernel import event_pool_launches
 
 __all__ = ["matmul", "linear", "conv2d", "maxpool2d",
            "pool_ineligible_reason", "route_conv", "route_pool",
@@ -315,16 +318,26 @@ def conv2d(x, w: jax.Array, b: jax.Array | None = None,
                 # (the BENCH per-layer compaction column reads these).
                 subtaps, subtaps_worst = ev.strip_subtap_counts(
                     k, padding, stride)
+                src = ev.strip_tap_map(x.logical_shape, k, padding,
+                                       stride)[0]
+                launches = event_conv_launches(
+                    src, x.events.capacity, int8=x.qparams is not None)
                 trace.record(op="conv2d", backend=name, chained=True,
-                             strip=True, launches=1, stride=stride,
+                             strip=True, launches=launches, stride=stride,
                              subtaps=subtaps, subtaps_worst=subtaps_worst,
                              compaction=subtaps / subtaps_worst,
                              **fields)
                 return get_backend("conv2d_events_strip", name)(
                     x, w, b, cfg, stride, padding)
             if dec.route == "pixel":
+                from repro.core.mnf_conv import conv_out_size
+                bsz, h, wd, _ = x.logical_shape
+                rows = bsz * conv_out_size(h, k, stride, padding) \
+                    * conv_out_size(wd, k, stride, padding)
+                launches = k * k * event_matmul_launches(
+                    rows, x.events.capacity, int8=x.qparams is not None)
                 trace.record(op="conv2d", backend=name, chained=True,
-                             launches=k * k, **fields)
+                             launches=launches, **fields)
                 return get_backend("conv2d_events", name)(x, w, b, cfg,
                                                           stride, padding)
             if dec.source == "geometry":
@@ -455,10 +468,17 @@ def maxpool2d(x, k: int, stride: int | None = None,
             if dec.is_event:
                 # "window" rides the window-major strip grid (one step per
                 # output strip); "pixel" the general per-event segment max.
-                op_name = ("maxpool2d_events_window" if dec.route == "window"
+                window = dec.route == "window"
+                op_name = ("maxpool2d_events_window" if window
                            else "maxpool2d_events")
+                src = (ev.pool_strip_map(x.logical_shape, k, stride)[0]
+                       if window else
+                       ev.pool_window_map(x.logical_shape, k, stride,
+                                          x.blk_m)[0])
+                launches = event_pool_launches(src, x.events.capacity,
+                                               window=window)
                 trace.record(op="maxpool2d", backend=name, chained=True,
-                             pool_events=True, launches=1, **fields)
+                             pool_events=True, launches=launches, **fields)
                 rows = get_backend(op_name, name)(x, k, stride, cfg)
             else:
                 # Dense by *choice* (adaptive / forced): pool the dense twin

@@ -36,7 +36,10 @@ for the Mosaic lowering cost comparison recorded in DESIGN.md §6.
 
 ``@pl.when(e < cnt[g, t])`` idles the unit on padded event slots and on
 border subtaps (zero-padding reads outside the map) — the paper's
-low-power idle, now covering the whole tap loop of a layer.
+low-power idle, now covering the whole tap loop of a layer.  The plan
+tables ride the scalar prefetch flat; where they outgrow SMEM the layer
+splits into launches over chunks of output strips (``kernels.smem``),
+each output tile still reduced by one launch in plan order.
 """
 from __future__ import annotations
 
@@ -44,11 +47,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.event_matmul.kernel import HIGHEST
+from repro.kernels.smem import src_chunks
+
 __all__ = ["event_conv_kernel", "event_conv_pallas",
-           "event_conv_int8_kernel", "event_conv_int8_pallas"]
+           "event_conv_int8_kernel", "event_conv_int8_pallas",
+           "event_conv_launches"]
 
 
 def _shift_rows(a, d, *, row_stride: int, remap: str):
@@ -70,7 +78,8 @@ def _shift_rows(a, d, *, row_stride: int, remap: str):
     i = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (bm, bm), 1)
     sel = (j == i * row_stride + d).astype(a.dtype)
-    return jnp.dot(sel, a, preferred_element_type=jnp.float32)
+    return jnp.dot(sel, a, preferred_element_type=jnp.float32,
+                   precision=HIGHEST)
 
 
 def event_conv_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
@@ -84,6 +93,7 @@ def event_conv_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
     e = pl.program_id(3)
     num_t = pl.num_programs(2)
     num_e = pl.num_programs(3)
+    gt = g * num_t + t
 
     @pl.when((t == 0) & (e == 0))
     def _init():
@@ -93,13 +103,14 @@ def event_conv_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
     def _tap_init():
         tap_acc_ref[...] = jnp.zeros_like(tap_acc_ref)
 
-    @pl.when(e < cnt_ref[g, t])
+    @pl.when(e < cnt_ref[gt])
     def _mac():
         a = a_vals_ref[0, 0]                     # (bm, bk) source strip tile
         shifted = _shift_rows(a, shift_ref[t], row_stride=row_stride,
                               remap=remap)
         tap_acc_ref[...] += jnp.dot(shifted, w_ref[...],
-                                    preferred_element_type=jnp.float32)
+                                    preferred_element_type=jnp.float32,
+                                    precision=HIGHEST)
 
     @pl.when(e == num_e - 1)
     def _tap_flush():
@@ -135,6 +146,7 @@ def event_conv_int8_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
     e = pl.program_id(3)
     num_t = pl.num_programs(2)
     num_e = pl.num_programs(3)
+    gt = g * num_t + t
 
     @pl.when((t == 0) & (e == 0))
     def _init():
@@ -144,14 +156,15 @@ def event_conv_int8_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
     def _tap_init():
         tap_acc_ref[...] = jnp.zeros_like(tap_acc_ref)
 
-    @pl.when(e < cnt_ref[g, t])
+    @pl.when(e < cnt_ref[gt])
     def _mac():
         a = a_vals_ref[0, 0].astype(jnp.float32)   # (bm, bk) int8 codes
         a = (a - zp_ref[0].astype(jnp.float32)) * scale_ref[0]
         shifted = _shift_rows(a, shift_ref[t], row_stride=row_stride,
                               remap=remap)
         tap_acc_ref[...] += jnp.dot(shifted, w_ref[...],
-                                    preferred_element_type=jnp.float32)
+                                    preferred_element_type=jnp.float32,
+                                    precision=HIGHEST)
 
     @pl.when(e == num_e - 1)
     def _tap_flush():
@@ -162,71 +175,95 @@ def event_conv_int8_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("nkb", "blk_n", "row_stride",
-                                             "interpret", "out_dtype",
-                                             "remap"))
-def event_conv_pallas(a_vals: jax.Array, a_idx: jax.Array, tap: jax.Array,
-                      shift: jax.Array, src: jax.Array, cnt: jax.Array,
-                      ws: jax.Array, *, nkb: int, blk_n: int = 128,
-                      row_stride: int = 1, interpret: bool = False,
-                      out_dtype=jnp.float32, remap: str = "matmul") -> jax.Array:
-    """One fused launch: y[g] = sum_t sum_e remap_t(a[src[g,t], e]) @ W_tile.
+def _chunks(src: np.ndarray, e: int, nq: int) -> list[tuple]:
+    """SMEM chunks of a strip plan (``kernels.smem``).  A launch prefetches
+    tap + shift (T each) and ``nq`` quant scalars once, src + cnt per
+    (output strip, subtap), and E addresses per source strip it reads."""
+    t_n = src.shape[1]
+    return src_chunks(src, row_words=2 * t_n, src_words=e,
+                      fixed_words=2 * t_n + nq)
 
-    a_vals/a_idx: strip-encoded events (G_in, E, bm, bk) / (G_in, E).
-    tap/shift: (T,) subtap plan, T the plan's **compacted** subtap count
-    (dead straddle parts already dropped — the grid axis is sized by the
-    plan handed in, not the worst case); src/cnt: (G_out, T) source strip
-    + live event count per (output strip, subtap).  ws: tap-stacked
-    weights (k*k*nkb*bk, N), N a multiple of blk_n.  ``row_stride`` is
-    the conv stride: out row i reads src row row_stride*i + shift[t].
-    ``remap`` picks the in-tile row-remap lowering ("matmul" | "select" —
-    bit-identical; see the kernel docstring).  Returns (G_out, bm, N).
-    """
+
+def event_conv_launches(src: np.ndarray, e: int, *, int8: bool = False) -> int:
+    """Pallas launches one fused strip conv issues for plan ``src``
+    (G_out × T) over E event slots — what trace records report."""
+    return len(_chunks(src, e, 2 if int8 else 0))
+
+
+def _launch(kernel, name: str, a_vals, a_idx, tap, shift, src, cnt, quant,
+            ws, *, nkb: int, blk_n: int, interpret: bool, out_dtype):
     g_in, e, bm, bk = a_vals.shape
     g_out, t_n = src.shape
     rows, n = ws.shape
-    assert remap in ("matmul", "select"), remap
     assert rows % (nkb * bk) == 0, (ws.shape, nkb, bk)  # k*k weight slabs
+    assert n % blk_n == 0, (n, blk_n)
+    outs = []
+    for r0, r1, s0, s1, src_c in _chunks(src, e, len(quant)):
+        spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5 + len(quant),
+            grid=(r1 - r0, n // blk_n, t_n, e),
+            in_specs=[
+                pl.BlockSpec((1, 1, bm, bk),
+                             lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, *_,
+                             s0=s0: (s0 + sr[gi * t_n + ti], ei, 0, 0)),
+                pl.BlockSpec((bk, blk_n),
+                             lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, *_:
+                             (tp[ti] * nkb + ai[sr[gi * t_n + ti] * e + ei],
+                              ni)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, blk_n),
+                                   lambda gi, ni, ti, ei, *_: (gi, 0, ni)),
+            scratch_shapes=[pltpu.VMEM((bm, blk_n), jnp.float32),
+                            pltpu.VMEM((bm, blk_n), jnp.float32)],
+        )
+        outs.append(pl.pallas_call(
+            kernel,
+            grid_spec=spec,
+            out_shape=jax.ShapeDtypeStruct((r1 - r0, bm, n), out_dtype),
+            interpret=interpret,
+            name=name,
+        )(jnp.asarray(tap), jnp.asarray(shift), jnp.asarray(src_c),
+          cnt[r0:r1].reshape(-1), a_idx[s0:s1].reshape(-1), *quant,
+          a_vals, ws))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+def event_conv_pallas(a_vals: jax.Array, a_idx: jax.Array, tap: np.ndarray,
+                      shift: np.ndarray, src: np.ndarray, cnt: jax.Array,
+                      ws: jax.Array, *, nkb: int, blk_n: int = 128,
+                      row_stride: int = 1, interpret: bool = False,
+                      out_dtype=jnp.float32, remap: str = "matmul") -> jax.Array:
+    """Fused launches: y[g] = sum_t sum_e remap_t(a[src[g,t], e]) @ W_tile.
+
+    a_vals/a_idx: strip-encoded events (G_in, E, bm, bk) / (G_in, E).
+    tap/shift: static (T,) subtap plan, T the plan's **compacted** subtap
+    count (dead straddle parts already dropped — the grid axis is sized by
+    the plan handed in, not the worst case); src: static (G_out, T) source
+    strip per (output strip, subtap); cnt: (G_out, T) live event counts.
+    ws: tap-stacked weights (k*k*nkb*bk, N), N a multiple of blk_n.
+    ``row_stride`` is the conv stride: out row i reads src row
+    row_stride*i + shift[t].  ``remap`` picks the in-tile row-remap
+    lowering ("matmul" | "select" — bit-identical; see the kernel
+    docstring).  One launch per SMEM chunk of output strips.  Returns
+    (G_out, bm, N).
+    """
+    g_in, e, bm, bk = a_vals.shape
+    rows = ws.shape[0]
+    t_n = src.shape[1]
+    assert remap in ("matmul", "select"), remap
     assert t_n <= (rows // (nkb * bk)) * \
         (((bm - 1) * row_stride + bm - 1) // bm + 1), \
         (t_n, ws.shape, nkb, bk, row_stride)
-    assert n % blk_n == 0, (n, blk_n)
-
-    grid = (g_out, n // blk_n, t_n, e)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk),
-                         lambda gi, ni, ti, ei, tp, sh, sr, ct, ai:
-                         (sr[gi, ti], ei, 0, 0)),
-            pl.BlockSpec((bk, blk_n),
-                         lambda gi, ni, ti, ei, tp, sh, sr, ct, ai:
-                         (tp[ti] * nkb + ai[sr[gi, ti], ei], ni)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, blk_n),
-                               lambda gi, ni, ti, ei, tp, sh, sr, ct, ai:
-                               (gi, 0, ni)),
-        scratch_shapes=[pltpu.VMEM((bm, blk_n), jnp.float32),
-                        pltpu.VMEM((bm, blk_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(event_conv_kernel, row_stride=row_stride,
-                          remap=remap),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((g_out, bm, n), out_dtype),
-        interpret=interpret,
-        name="mnf_event_conv_fused",
-    )(tap, shift, src, cnt, a_idx, a_vals, ws)
-    return out
+    kernel = functools.partial(event_conv_kernel, row_stride=row_stride,
+                               remap=remap)
+    return _launch(kernel, "mnf_event_conv_fused", a_vals, a_idx, tap, shift,
+                   src, cnt, (), ws, nkb=nkb, blk_n=blk_n,
+                   interpret=interpret, out_dtype=out_dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("nkb", "blk_n", "row_stride",
-                                             "interpret", "out_dtype",
-                                             "remap"))
 def event_conv_int8_pallas(a_vals: jax.Array, a_idx: jax.Array,
-                           tap: jax.Array, shift: jax.Array, src: jax.Array,
-                           cnt: jax.Array, scale: jax.Array,
+                           tap: np.ndarray, shift: np.ndarray,
+                           src: np.ndarray, cnt: jax.Array, scale: jax.Array,
                            zero_point: jax.Array, ws: jax.Array, *, nkb: int,
                            blk_n: int = 128, row_stride: int = 1,
                            interpret: bool = False, out_dtype=jnp.float32,
@@ -239,40 +276,12 @@ def event_conv_int8_pallas(a_vals: jax.Array, a_idx: jax.Array,
     in f32 accumulation, bit-identical to the f32 kernel fed the
     fake-quant twin.
     """
-    g_in, e, bm, bk = a_vals.shape
-    g_out, t_n = src.shape
-    rows, n = ws.shape
     assert remap in ("matmul", "select"), remap
     assert a_vals.dtype == jnp.int8, a_vals.dtype
-    assert rows % (nkb * bk) == 0, (ws.shape, nkb, bk)
-    assert n % blk_n == 0, (n, blk_n)
-
-    grid = (g_out, n // blk_n, t_n, e)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk),
-                         lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, sc, zp:
-                         (sr[gi, ti], ei, 0, 0)),
-            pl.BlockSpec((bk, blk_n),
-                         lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, sc, zp:
-                         (tp[ti] * nkb + ai[sr[gi, ti], ei], ni)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, blk_n),
-                               lambda gi, ni, ti, ei, tp, sh, sr, ct, ai,
-                               sc, zp: (gi, 0, ni)),
-        scratch_shapes=[pltpu.VMEM((bm, blk_n), jnp.float32),
-                        pltpu.VMEM((bm, blk_n), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(event_conv_int8_kernel, row_stride=row_stride,
-                          remap=remap),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((g_out, bm, n), out_dtype),
-        interpret=interpret,
-        name="mnf_event_conv_fused_int8",
-    )(tap, shift, src, cnt, a_idx,
-      scale.reshape(1).astype(jnp.float32),
-      zero_point.reshape(1).astype(jnp.int32), a_vals, ws)
-    return out
+    kernel = functools.partial(event_conv_int8_kernel, row_stride=row_stride,
+                               remap=remap)
+    quant = (scale.reshape(1).astype(jnp.float32),
+             zero_point.reshape(1).astype(jnp.int32))
+    return _launch(kernel, "mnf_event_conv_fused_int8", a_vals, a_idx, tap,
+                   shift, src, cnt, quant, ws, nkb=nkb, blk_n=blk_n,
+                   interpret=interpret, out_dtype=out_dtype)

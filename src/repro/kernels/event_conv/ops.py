@@ -2,7 +2,8 @@
 
 ``fused_event_conv2d`` consumes a strip-aligned conv ``EventStream``
 (blk_m == STRIP_W, NHWC ``logical_shape``) and computes the whole conv layer
-in **one** Pallas launch — the engine registry's "pallas" backend of
+in **one** Pallas launch per SMEM chunk (``kernels.smem`` — one launch at
+every width whose plan fits) — the engine registry's "pallas" backend of
 ``conv2d_events_strip``.  ``fused_conv_plan`` exposes the static launch
 accounting (grid size, launches, event-grid reduction vs the per-tap path)
 that the benchmarks and BENCH_engine.json report.
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 from repro.core import events as ev
 from repro.core.mnf_conv import conv_out_size
 from repro.kernels.event_conv.kernel import (event_conv_int8_pallas,
+                                             event_conv_launches,
                                              event_conv_pallas)
 
 __all__ = ["fused_event_conv2d", "fused_conv_plan"]
@@ -41,7 +43,8 @@ def fused_event_conv2d(stream, w: jax.Array, *, stride: int = 1,
                        padding: int = 0, blk_n: int = 128,
                        interpret: bool = False,
                        remap: str = "matmul") -> jax.Array:
-    """Strip-tiled fused-tap conv, one Pallas launch.  Returns (B*OY*OX, CO).
+    """Strip-tiled fused-tap conv, one Pallas launch per SMEM chunk.
+    Returns (B*OY*OX, CO).
 
     ``stream`` must be strip-aligned (blk_m == STRIP_W) and the layer
     strip-eligible (stride in STRIP_STRIDES — see
@@ -58,18 +61,16 @@ def fused_event_conv2d(stream, w: jax.Array, *, stride: int = 1,
     nkb = bev.num_k_blocks
     src, live, shift, tap = ev.strip_tap_map((b, h, wd, ci), k, padding,
                                              stride)
-    src_j = jnp.asarray(src)
-    cnt = jnp.where(jnp.asarray(live), bev.counts[src_j], 0)
+    cnt = jnp.where(jnp.asarray(live), bev.counts[jnp.asarray(src)], 0)
+    cnt = cnt.astype(jnp.int32)
     ws = _stacked_weights(w, bk, nkb, blk_n)
     if stream.qparams is not None:
         y = event_conv_int8_pallas(
-            bev.values, bev.block_idx, jnp.asarray(tap), jnp.asarray(shift),
-            src_j, cnt.astype(jnp.int32), stream.qparams.scale,
-            stream.qparams.zero_point, ws, nkb=nkb, blk_n=blk_n,
-            row_stride=stride, interpret=interpret, remap=remap)
+            bev.values, bev.block_idx, tap, shift, src, cnt,
+            stream.qparams.scale, stream.qparams.zero_point, ws, nkb=nkb,
+            blk_n=blk_n, row_stride=stride, interpret=interpret, remap=remap)
     else:
-        y = event_conv_pallas(bev.values, bev.block_idx, jnp.asarray(tap),
-                              jnp.asarray(shift), src_j, cnt.astype(jnp.int32),
+        y = event_conv_pallas(bev.values, bev.block_idx, tap, shift, src, cnt,
                               ws, nkb=nkb, blk_n=blk_n, row_stride=stride,
                               interpret=interpret, remap=remap)
     oy = conv_out_size(h, k, stride, padding)
@@ -98,8 +99,9 @@ def fused_conv_plan(logical_shape: tuple, k: int, padding: int,
     g_strip = g_pix // ev.STRIP_W
     g_out = b * oh * (ow // ev.STRIP_W)
     subtaps, subtaps_worst = ev.strip_subtap_counts(k, padding, stride)
+    src, _, _, _ = ev.strip_tap_map(logical_shape, k, padding, stride)
     return dict(
-        launches_fused=1, launches_per_tap=k * k,
+        launches_fused=event_conv_launches(src, e), launches_per_tap=k * k,
         grid_fused=(g_out, subtaps, e),
         subtaps=subtaps, subtaps_worst=subtaps_worst,
         compaction=subtaps / subtaps_worst,

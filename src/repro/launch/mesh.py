@@ -5,26 +5,20 @@ call only.  Dry-run processes must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before any jax
 import* (launch/dryrun.py does this in its first two lines).
 
-All mesh construction in the repo funnels through :func:`checked_mesh`:
-
-  * **Capacity-checked.**  Requesting more mesh slots than the runtime has
-    devices used to surface as a raw XLA/``make_mesh`` assertion deep in
-    jax internals.  ``checked_mesh`` raises :class:`MeshCapacityError` —
-    a named, actionable error that says how many devices exist, how many
-    the shape needs, and how to get them (``XLA_FLAGS`` host-device
-    forcing, or a smaller shape).  ``fallback=True`` degrades to a 1×1
-    (or 1×…×1) mesh with a warning instead — what a single-device serving
-    replica wants.
-  * **Version-compatible.**  ``axis_types=`` only exists on newer jax;
-    passing it unconditionally breaks jax 0.4.x at call time.  The helper
-    feeds it only when ``jax.make_mesh`` accepts it.
+All mesh construction in the repo funnels through :func:`checked_mesh`,
+which is capacity-checked: requesting more mesh slots than the runtime has
+devices used to surface as a raw XLA/``make_mesh`` assertion deep in jax
+internals.  ``checked_mesh`` raises :class:`MeshCapacityError` — a named,
+actionable error that says how many devices exist, how many the shape
+needs, and how to get them (``XLA_FLAGS`` host-device forcing, or a
+smaller shape).  No mesh ever degrades to a smaller one: a replica that
+asked for four devices and silently got one would hide the device it was
+meant to use.
 """
 from __future__ import annotations
 
-import inspect
-import warnings
-
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["MeshCapacityError", "checked_mesh", "make_production_mesh",
            "make_serve_mesh", "make_small_mesh"]
@@ -34,42 +28,22 @@ class MeshCapacityError(RuntimeError):
     """Requested mesh shape needs more devices than the runtime has."""
 
 
-def _auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on jax versions that have it, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
-def checked_mesh(shape, axes, *, fallback: bool = False):
-    """``jax.make_mesh`` with a capacity check and version-compat kwargs.
-
-    Raises :class:`MeshCapacityError` (named, actionable) when ``shape``
-    needs more devices than ``jax.devices()`` provides; with
-    ``fallback=True`` it instead warns and returns the all-ones mesh over
-    the same axis names (a single-device replica keeps serving).
-    """
+def checked_mesh(shape, axes):
+    """``jax.make_mesh`` (every axis ``AxisType.Auto``) with a capacity
+    check: raises :class:`MeshCapacityError` (named, actionable) when
+    ``shape`` needs more devices than ``jax.devices()`` provides."""
     need = 1
     for s in shape:
         need *= int(s)
     have = len(jax.devices())
     if need > have:
-        msg = (f"mesh shape {tuple(shape)} over axes {tuple(axes)} needs "
-               f"{need} devices but only {have} exist. Either request a "
-               f"smaller mesh, or force host devices before any jax import "
-               f"(XLA_FLAGS=--xla_force_host_platform_device_count={need}).")
-        if not fallback:
-            raise MeshCapacityError(msg)
-        warnings.warn(f"{msg} Falling back to a 1x1 mesh.", RuntimeWarning,
-                      stacklevel=2)
-        shape = (1,) * len(shape)
-    kw = {}
-    types = _auto_axis_types(len(axes))
-    if types is not None and \
-            "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        kw["axis_types"] = types
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+        raise MeshCapacityError(
+            f"mesh shape {tuple(shape)} over axes {tuple(axes)} needs "
+            f"{need} devices but only {have} exist. Either request a "
+            f"smaller mesh, or force host devices before any jax import "
+            f"(XLA_FLAGS=--xla_force_host_platform_device_count={need}).")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -84,16 +58,13 @@ def make_small_mesh(shape=(2, 4), axes=("data", "model")):
     return checked_mesh(shape, axes)
 
 
-def make_serve_mesh(data: int | None = None, model: int = 1, *,
-                    fallback: bool = True):
+def make_serve_mesh(data: int | None = None, model: int = 1):
     """The serving tier's (data, model) mesh: batch axis over every device.
 
     ``data=None`` spans all visible devices (the replica default: weights
     replicated, batch sharded on ``data``).  An explicit shape that exceeds
-    the device count warns and degrades to 1×1 (``fallback=True`` — a
-    replica must come up, not crash) or raises :class:`MeshCapacityError`
-    with ``fallback=False``.
+    the device count raises :class:`MeshCapacityError`.
     """
     if data is None:
         data = max(len(jax.devices()) // model, 1)
-    return checked_mesh((data, model), ("data", "model"), fallback=fallback)
+    return checked_mesh((data, model), ("data", "model"))

@@ -10,7 +10,7 @@ axis sharded over the (data, model) mesh.  MNF is the default; ``--dense``
 serves the oracle path instead:
 
   PYTHONPATH=src python -m repro.launch.serve --cnn alexnet --cnn-size 64 \
-      --rate 6 --ticks 8 --cache-dir /tmp/mnf_cache
+      --rate 6 --ticks 8 --cache-dir snapshots
 
 ``--smoke`` serves the mini network through every bucket and **fails**
 (exit 1) if any steady-state tick recompiles, or an eligible event
@@ -61,6 +61,7 @@ def serve_cnn(args) -> None:
     from repro.models.cnn import init_cnn_params
     from repro.models.mlp import init_mlp_params
 
+    serving.configure_persistent_cache()
     if args.mlp:
         spec = _mlp_spec(args.mlp)
     else:
@@ -150,7 +151,10 @@ def serve_smoke(args) -> None:
 
     spec = _cnn_spec("mini", 8)
     buckets = (1, 2, 4)
-    cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="mnf_serve_smoke_")
+    # Snapshots of this run only (the restart check must restore what this
+    # replica compiled); the XLA compile cache keeps its one home.
+    snapshots = tempfile.TemporaryDirectory(prefix="mnf_serve_smoke_")
+    cache_dir = args.cache_dir or snapshots.name
     params = init_cnn_params(jax.random.PRNGKey(0), spec,
                              weight_sparsity=0.5)
     eng = serving.ServeEngine(
@@ -270,7 +274,10 @@ def main():
     ap.add_argument("--mnf-threshold", type=float, default=0.0)
     ap.add_argument("--mnf-pallas", action="store_true",
                     help="route the MNF multiply phase through the Pallas "
-                         "engine backend (default: pure-XLA block backend)")
+                         "engine backend (default: CNN/MLP mode resolves "
+                         "per device — Pallas on a TPU, the pure-XLA block "
+                         "backend elsewhere; LM mode uses the block "
+                         "backend)")
     ap.add_argument("--cnn", choices=("alexnet", "vgg16", "alexnet_ds",
                                       "vgg16_ds", "mini"),
                     help="serve a CNN workload through the bucketed "
@@ -292,9 +299,11 @@ def main():
     ap.add_argument("--ticks", type=int, default=8,
                     help="CNN mode: number of serving ticks to run")
     ap.add_argument("--cache-dir", default=None,
-                    help="JAX persistent compilation cache directory — a "
-                         "restarted replica re-warms its bucket "
-                         "executables from disk in seconds")
+                    help="executable snapshot directory — a restarted "
+                         "replica re-warms its bucket executables from "
+                         "disk in seconds (the XLA compile cache lives at "
+                         "JAX_COMPILATION_CACHE_DIR, else .jax_cache/ in "
+                         "the checkout)")
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: tiny bucketed serve loop; exit 1 on any "
                          "steady-state recompile, fallback_decode, or "

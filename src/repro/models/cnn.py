@@ -363,7 +363,7 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
 
 def _layer_cfg(base: engine.EngineConfig | None, *, mnf: bool,
                fire_cfg: FireConfig) -> engine.EngineConfig:
-    cfg = base or engine.EngineConfig(backend="block")
+    cfg = base or engine.EngineConfig()
     if not mnf:
         cfg = cfg.replace(backend="dense")
     return cfg.replace(threshold=fire_cfg.threshold,
@@ -669,7 +669,8 @@ def cnn_forward(params, x: jax.Array, spec: CNNSpec, *, mnf: bool = True,
     """x: (B, H, W, C) -> logits (B, classes).  mnf=False is the oracle.
 
     All compute dispatches through ``repro.engine``; ``engine_cfg`` picks
-    the backend (default: pure-jnp block events).  ``chain`` selects the
+    the backend (default ``"auto"``: the Pallas kernels on a TPU, pure-jnp
+    block events elsewhere).  ``chain`` selects the
     event-resident path (default: on for MNF; int8 requantization chains
     too — fire emits int8 event values and the round-trip twin is the
     fake-quant forward, DESIGN.md §12); ``chain=False`` forces the

@@ -124,7 +124,7 @@ def mlp_boundary_summary(spec: MLPSpec, *, batch: int = 1,
 
 def _mlp_cfg(base: engine.EngineConfig | None, *, mnf: bool,
              fire_cfg: FireConfig) -> engine.EngineConfig:
-    cfg = base or engine.EngineConfig(backend="block")
+    cfg = base or engine.EngineConfig()
     if not mnf:
         cfg = cfg.replace(backend="dense")
     return cfg.replace(threshold=fire_cfg.threshold,

@@ -19,8 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardingRules", "make_rules", "logical_to_pspec",
            "named_sharding_tree", "make_sharder", "mesh_axis_size",
-           "abstract_mesh_compat", "data_axis_size", "serve_batch_pspec",
-           "shard_map_compat"]
+           "data_axis_size", "serve_batch_pspec"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,19 +119,6 @@ def named_sharding_tree(specs, shapes, mesh: Mesh, rules: ShardingRules):
     return jax.tree.map(resolve, specs, shapes, is_leaf=is_axes)
 
 
-def abstract_mesh_compat(axis_sizes, axis_names) -> "jax.sharding.AbstractMesh":
-    """``AbstractMesh`` across jax versions: 0.4.x takes one
-    ``((name, size), ...)`` shape tuple, newer jax takes ``(sizes, names)``.
-    Shape arithmetic only — no devices behind it, so rule-table resolution
-    can be tested at any mesh size on a 1-device box."""
-    import inspect as _inspect
-    am = jax.sharding.AbstractMesh
-    params = list(_inspect.signature(am.__init__).parameters)
-    if params[1] == "shape_tuple":          # jax 0.4.x
-        return am(tuple(zip(axis_names, axis_sizes)))
-    return am(tuple(axis_sizes), tuple(axis_names))
-
-
 def data_axis_size(mesh: Mesh) -> int:
     """Total data-parallel width of a ("pod"?, "data", ...) mesh."""
     return int(np.prod([mesh.shape[a] for a in ("pod", "data")
@@ -151,26 +137,6 @@ def serve_batch_pspec(mesh: Mesh, batch: int, ndim: int = 4,
     rules = rules or make_rules(mesh)
     axes = ("batch",) + (None,) * (ndim - 1)
     return logical_to_pspec(axes, (batch,) + (1,) * (ndim - 1), mesh, rules)
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (top-level vs experimental API).
-
-    Replication checking is disabled: the event pipeline's gather/segment
-    ops predate rep rules on older jax, and the serving tier's out_specs
-    never claim replication the body doesn't establish.
-    """
-    import inspect as _inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = {}
-    params = _inspect.signature(sm).parameters
-    for flag in ("check_rep", "check_vma"):
-        if flag in params:
-            kw[flag] = False
-            break
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 def make_sharder(mesh: Mesh, rules: ShardingRules):

@@ -32,9 +32,8 @@ import numpy as np
 from repro import engine as mnf_engine
 from repro.core.fire import FireConfig
 from repro.launch.steps import make_cnn_serve_step
-from repro.serving.aot import (aot_compile, configure_persistent_cache,
-                               load_executable, save_executable,
-                               snapshot_key)
+from repro.serving.aot import (aot_compile, load_executable,
+                               save_executable, snapshot_key)
 from repro.serving.batcher import (DEFAULT_BUCKETS, ContinuousBatcher,
                                    Request, pad_bucket)
 
@@ -49,10 +48,11 @@ class ServeEngineConfig:
                   to the smallest admissible one).
     mnf:          event-resident pipeline (False = dense oracle serving).
     backend/threshold: forwarded into the per-bucket EngineConfig.
-    cache_dir:    warm-start directory (None = off): holds both the JAX
-                  persistent compilation cache and per-bucket executable
-                  snapshots, so a restarted replica restores finished
-                  executables from disk without tracing or compiling.
+    cache_dir:    warm-start directory (None = off) for per-bucket
+                  executable snapshots, so a restarted replica restores
+                  finished executables from disk without tracing or
+                  compiling.  Bucket compiles then bypass the JAX
+                  persistent compilation cache (``aot_compile(fresh=True)``).
     aot_warmup:   compile every bucket at startup (False defers each bucket
                   to its first request — only for tests/latency studies).
     max_batches_per_tick: tick batch budget (None = drain the queue).
@@ -81,8 +81,6 @@ class ServeEngine:
                  mesh=None, engine_cfg=None, fire_cfg=None):
         self.cfg = cfg or ServeEngineConfig()
         self.spec = spec
-        if self.cfg.cache_dir:
-            configure_persistent_cache(self.cfg.cache_dir)
         if mesh is None:
             from repro.launch.mesh import make_serve_mesh
             mesh = make_serve_mesh()
@@ -161,8 +159,8 @@ class ServeEngine:
                         load_s=round(time.perf_counter() - t0, 4))
                     return restored
             self.recompiles += 1
-            compiled, lower_s, compile_s = aot_compile(plan.fn,
-                                                       plan.arg_specs)
+            compiled, lower_s, compile_s = aot_compile(
+                plan.fn, plan.arg_specs, fresh=bool(self.cfg.cache_dir))
             self._exec[bucket] = compiled
             self.warmup_s[bucket] = dict(lower_s=round(lower_s, 4),
                                          compile_s=round(compile_s, 4))

@@ -160,3 +160,47 @@ def test_mamba_scan_initial_state(rng):
     yr, hr = mamba_scan_ref(da, dbx, c, h0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-3)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), atol=1e-3)
+
+
+@pytest.mark.parametrize("op", ["matmul", "pool_pixel", "pool_window",
+                                "conv_strip"])
+def test_smem_chunked_launches_bitwise(monkeypatch, op):
+    """A launch split into SMEM chunks (kernels.smem) computes every output
+    tile in one launch in the same order: bitwise the one-launch result,
+    with the extra launches counted in the trace."""
+    from repro import engine
+    from repro.kernels import smem
+    from repro.kernels.event_matmul.kernel import event_matmul_launches
+
+    r = np.random.default_rng(7)
+    shape = (2, 8, 16, 12)
+    x = jax.nn.relu(jnp.asarray(r.normal(size=shape), jnp.float32))
+    cfg = engine.EngineConfig(backend="pallas", blk_m=1, blk_k=4)
+    if op == "matmul":
+        w = jnp.asarray(r.normal(size=(256, 128)), jnp.float32)
+        a = x.reshape(16, 192)
+        a = jnp.pad(a, ((0, 0), (0, 64)))
+
+        def run():   # 16 one-row groups of 32 K-block events
+            return (event_matmul(a, w, blk_m=1, blk_k=8, interpret=True),
+                    event_matmul_launches(16, 32))
+    else:
+        wc = jnp.asarray(r.normal(size=(3, 3, 12, 8)), jnp.float32)
+
+        def run():
+            stream = engine.fire_conv(x, cfg, keep_dense=False,
+                                      blk_m=1 if op == "pool_pixel" else 8)
+            with engine.trace_dispatch() as recs:
+                if op == "conv_strip":
+                    y = engine.conv2d(stream, wc, cfg=cfg.for_conv(12),
+                                      padding=1)
+                else:
+                    y = engine.maxpool2d(stream, 2, 2, cfg=cfg).dense_nhwc()
+            return y, sum(rec.get("launches", 0) for rec in recs)
+
+    whole, n_whole = run()
+    monkeypatch.setattr(smem, "SMEM_PLAN_WORDS", 160)
+    jax.clear_caches()
+    split, n_split = run()
+    assert bool(jnp.array_equal(whole, split)), op
+    assert n_split > n_whole >= 1, (n_split, n_whole)

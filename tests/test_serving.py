@@ -105,11 +105,11 @@ def test_mesh_capacity_error_is_actionable():
     assert "16384" in msg and "xla_force_host_platform_device_count" in msg
 
 
-def test_mesh_capacity_fallback_warns_to_ones():
-    with pytest.warns(RuntimeWarning, match="Falling back"):
-        mesh = checked_mesh((8192, 2), ("data", "model"), fallback=True)
-    assert dict(zip(mesh.axis_names, mesh.devices.shape)) == \
-        {"data": 1, "model": 1}
+def test_make_serve_mesh_oversized_raises():
+    """An oversized serving mesh is an error, never a silent 1x1 replica."""
+    need = len(jax.devices()) + 1
+    with pytest.raises(MeshCapacityError, match=f"needs {need} devices"):
+        make_serve_mesh(data=need)
 
 
 def test_make_serve_mesh_spans_devices():
@@ -208,6 +208,34 @@ def test_executable_snapshot_restore(served, tmp_path):
     y1 = np.asarray(first._compiled(1)(first.params, first._place(1, x)))
     y2 = np.asarray(second._compiled(1)(second.params, second._place(1, x)))
     np.testing.assert_array_equal(y1, y2)
+
+
+def test_executable_snapshot_restore_with_warm_compile_cache(
+        served, tmp_path, monkeypatch):
+    """With the XLA compile cache on and already holding the bucket, the
+    snapshotting replica still compiles fresh: an executable read back from
+    that cache does not survive serialize -> deserialize on XLA:CPU."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.serving import aot
+    spec, params, _, images = served
+    monkeypatch.setattr(aot, "DEFAULT_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    aot.configure_persistent_cache()
+    try:
+        ServeEngine(spec, params, ServeEngineConfig(buckets=(1,)))  # warms it
+        cfg = ServeEngineConfig(buckets=(1,), cache_dir=str(tmp_path / "s"))
+        first = ServeEngine(spec, params, cfg)
+        second = ServeEngine(spec, params, cfg)
+        assert second.snapshot_hits == 1 and second.recompiles == 0
+        x = pad_bucket([images[0]], 1)
+        y1 = np.asarray(first._compiled(1)(first.params, first._place(1, x)))
+        y2 = np.asarray(second._compiled(1)(second.params,
+                                            second._place(1, x)))
+        np.testing.assert_array_equal(y1, y2)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()
 
 
 def test_stats_report(served):

@@ -1,15 +1,14 @@
 """Logical-axis resolver: priority, divisibility, reuse (no multi-device)."""
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.parallel.sharding import (abstract_mesh_compat, logical_to_pspec,
-                                     make_rules)
+from repro.parallel.sharding import logical_to_pspec, make_rules
 
 
 @pytest.fixture(scope="module")
 def mesh16():
     # abstract mesh: shape arithmetic only, no devices needed
-    return abstract_mesh_compat((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_divisibility_drops_heads(mesh16):
@@ -52,7 +51,7 @@ def test_fsdp_rule(mesh16):
 
 
 def test_batch_over_pod_and_data():
-    mesh = abstract_mesh_compat((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     rules = make_rules(mesh)
     ps = logical_to_pspec(("batch", None), (256, 4096), mesh, rules)
     assert ps == P(("pod", "data"))
@@ -62,7 +61,7 @@ def test_batch_over_pod_and_data():
 
 
 def test_overrides():
-    mesh = abstract_mesh_compat((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     rules = make_rules(mesh, overrides={"ff": None})
     ps = logical_to_pspec(("embed", "ff"), (1024, 4096), mesh, rules)
     assert ps == P()
