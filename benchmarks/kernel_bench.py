@@ -111,6 +111,11 @@ def rows(reps=3):
     return out
 
 
+def _seconds(warmup: dict) -> float:
+    """Seconds of one bucket's ``ServeEngine.warmup_s`` entry."""
+    return sum(v for k, v in warmup.items() if k.endswith("_s"))
+
+
 def _merge_bench(out_path: str, entries, drop_kinds: set):
     """Read-modify-write BENCH_engine.json: each mode owns its entry kinds."""
     dev = jax.devices()[0]
@@ -761,9 +766,10 @@ def serve_rows(out_path: str = "BENCH_engine.json", *, smoke=False, reps=3):
                 f"from the bucket-1 forward beyond kernel-selection noise")
         stats_b = eng.stats()["per_bucket"][b]
         # cold warmup = lower+compile seconds; warm warmup = either an
-        # executable-snapshot load_s or a cache-assisted recompile.
-        compile_cold_us = sum(cold_warmup_s[b].values()) * 1e6
-        warm_us = sum(eng.warmup_s[b].values()) * 1e6
+        # executable-snapshot load_s or a cache-assisted recompile (the
+        # entries also hold the memory plan's bytes: seconds end in _s).
+        compile_cold_us = _seconds(cold_warmup_s[b]) * 1e6
+        warm_us = _seconds(eng.warmup_s[b]) * 1e6
         entries.append(dict(
             kind="serve_bench", net=spec.name, input_size=spec.input_size,
             bucket=b, requests=stats_b["requests"],

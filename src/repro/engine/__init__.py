@@ -36,7 +36,7 @@ from repro.engine.config import BACKENDS, RECURRENT_BLK_K, EngineConfig
 from repro.engine.registry import (dispatch, get_backend, list_backends,
                                    register_backend, registered_ops)
 from repro.engine.stream import EventStream
-from repro.engine.trace import trace_dispatch
+from repro.engine.trace import scope, trace_dispatch
 
 import repro.engine.backends  # noqa: F401  (registers built-in backends)
 
@@ -51,5 +51,5 @@ __all__ = [
     "route_conv", "route_pool", "route_linear", "route_recurrent",
     "recurrent_ineligible_reason", "recurrent_step",
     "fire", "fire_conv", "fire_delta", "sparsify", "describe",
-    "trace_dispatch",
+    "trace_dispatch", "scope",
 ]

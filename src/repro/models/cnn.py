@@ -48,7 +48,8 @@ __all__ = ["ConvSpec", "FCSpec", "PoolSpec", "CNNSpec", "ALEXNET", "VGG16",
            "ALEXNET_DS", "ALEXNET_FF", "VGG16_DS", "MINI", "MINI_S4",
            "conv_downsampled", "init_cnn_params", "cnn_forward",
            "make_cnn_forward", "make_cnn_pipeline", "run_with_stats",
-           "layer_dense_macs", "chain_boundary_summary", "fc_in_events"]
+           "layer_dense_macs", "chain_boundary_summary", "fc_in_events",
+           "layer_scope"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +69,16 @@ class PoolSpec:
 @dataclasses.dataclass(frozen=True)
 class FCSpec:
     out: int
+
+
+_KINDS = {ConvSpec: "conv", PoolSpec: "pool", FCSpec: "fc"}
+
+
+def layer_scope(i: int, layer) -> str:
+    """The named scope of layer ``i`` of ``spec.layers``: ``L<i>.<kind>``
+    (``L03.conv``), with ``i`` the index in the configuration's layer list
+    and ``kind`` one of conv, pool, fc."""
+    return f"L{i:02d}.{_KINDS[type(layer)]}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -530,136 +541,146 @@ def _forward(params, x, spec: CNNSpec, *, mnf: bool, fire_cfg: FireConfig,
     # (DESIGN.md §6).
     conv_base = cfg.replace(blk_m=1, blk_k=min(8, cfg.blk_k))
     for i, (layer, wgt) in enumerate(zip(layers, params)):
-        nxt = layers[i + 1] if i + 1 < len(layers) else None
-        if isinstance(layer, ConvSpec):
-            if chain and not isinstance(x, engine.EventStream):
-                # Chain head (or densified seam): strip-encode the dense
-                # input when this conv can ride the fused kernel off it —
-                # the stride-4 AlexNet conv1 goes from k² per-tap event
-                # matmuls to one launch.  Lossless at threshold 0, bitwise
-                # vs the dense dispatch (see _input_stream_blk_m).
-                bm_in = _input_stream_blk_m(layer, tuple(x.shape), conv_base)
-                if bm_in:
-                    x = engine.EventStream.encode_nhwc(
-                        x, blk_k=min(conv_base.blk_k, max(x.shape[-1], 1)),
-                        blk_m=bm_in, keep_dense=False)
-            ci = x.logical_shape[-1] if isinstance(x, engine.EventStream) \
-                else x.shape[-1]
-            ccfg = conv_base.replace(threshold=0.0).for_conv(ci)
-            if stats is not None:
-                nzmap, (b, h, w, c) = _pixel_events(x)   # twin-free on chain
-                touched = _touched_outputs(h, w, layer.k, layer.stride,
-                                           layer.padding)
-                stats.append(dict(
-                    event_macs=jnp.sum(nzmap * touched[None].astype(
-                        jnp.float32)) * layer.out_ch,
-                    in_events=jnp.sum(nzmap)))
-            acc = engine.conv2d(x, wgt, cfg=ccfg, stride=layer.stride,
-                                padding=layer.padding)
-            if chain:
-                # Drop the dense twin at conv→conv boundaries, at
-                # conv→pool boundaries the event-native pool will consume,
-                # AND at conv→FC seams the re-tiler serves (events-only —
-                # instrumentation reads event values, never the twin);
-                # keep it only where an ineligible consumer genuinely
-                # reads it densely.
-                pool_chains = (isinstance(nxt, PoolSpec)
-                               and engine.pool_ineligible_reason(
-                                   tuple(acc.shape), nxt.k, nxt.stride,
-                                   conv_base) is None)
-                bm_next = _next_conv_blk_m(nxt, tuple(acc.shape))
-                keep = not (isinstance(nxt, ConvSpec) or pool_chains
-                            or _fc_chains(nxt, tuple(acc.shape), conv_base,
-                                          bm_next))
-                if not keep and conv_base.route != "auto":
-                    # Adaptive/forced routing may send the next boundary
-                    # dense; keep the twin so its ``dense_nhwc`` is a free
-                    # read, not a decode.  Same decision function the
-                    # dispatch uses — plan and dispatch cannot disagree.
-                    keep = not _next_boundary_route(
-                        nxt, tuple(acc.shape), conv_base, bm_next).is_event
-                x = engine.fire_conv(acc, conv_base, keep_dense=keep,
-                                     blk_m=bm_next)
-            else:
-                x = fire(acc, fire_cfg)              # fire phase == ReLU @ 0
-            if stats is not None:
-                stats[-1]["out_density"] = _density(x)
-        elif isinstance(layer, PoolSpec):
-            if chain and isinstance(x, engine.EventStream) \
-                    and engine.pool_ineligible_reason(
-                        x, layer.k, layer.stride, conv_base) is None:
-                # Event-native pool (DESIGN.md §7): segment max over the
-                # stream's events, re-emitted at the granularity the
-                # consumer wants — conv→pool→conv stays events-only (no
-                # twin, no re-encode).  The pooled twin is kept only when
-                # the FC head (or the network output) reads it densely.
-                c = x.logical_shape[-1]
-                oh = (x.logical_shape[1] - layer.k) // layer.stride + 1
-                pw = (x.logical_shape[2] - layer.k) // layer.stride + 1
-                pooled_shape = (x.logical_shape[0], oh, pw, c)
-                # Emitted granularity from the consumer (same rule as the
-                # conv fire): strips for a strip-eligible conv *or* a
-                # window-eligible next pool, pixels otherwise.
-                pcfg = conv_base.for_pool(c).replace(
-                    blk_m=_next_conv_blk_m(nxt, pooled_shape))
-                keep_pool = not (isinstance(nxt, ConvSpec)
-                                 or _fc_chains(nxt, pooled_shape, conv_base,
-                                               pcfg.blk_m))
-                if not keep_pool and conv_base.route != "auto":
-                    keep_pool = not _next_boundary_route(
-                        nxt, pooled_shape, conv_base,
-                        pcfg.blk_m).is_event
-                x = engine.maxpool2d(x, layer.k, layer.stride, cfg=pcfg,
-                                     keep_dense=keep_pool)
-            else:
-                pooled = max_pool_nhwc(_dense_nhwc(x), layer.k, layer.stride)
-                if chain and isinstance(nxt, ConvSpec):
-                    # Dense-pool fallback (round-trip twin, or a stream the
-                    # event pool cannot consume): re-encode at the
-                    # granularity the next conv consumes.
-                    x = engine.EventStream.encode_nhwc(
-                        pooled, blk_k=conv_base.blk_k,
-                        blk_m=_next_conv_blk_m(nxt, tuple(pooled.shape)),
-                        keep_dense=False)
+        with engine.scope(layer_scope(i, layer)):
+            nxt = layers[i + 1] if i + 1 < len(layers) else None
+            if isinstance(layer, ConvSpec):
+                if chain and not isinstance(x, engine.EventStream):
+                    # Chain head (or densified seam): strip-encode the
+                    # dense input when this conv can ride the fused kernel
+                    # off it — the stride-4 AlexNet conv1 goes from k²
+                    # per-tap event matmuls to one launch.  Lossless at
+                    # threshold 0, bitwise vs the dense dispatch (see
+                    # _input_stream_blk_m).
+                    bm_in = _input_stream_blk_m(layer, tuple(x.shape),
+                                                conv_base)
+                    if bm_in:
+                        x = engine.EventStream.encode_nhwc(
+                            x, blk_k=min(conv_base.blk_k,
+                                         max(x.shape[-1], 1)),
+                            blk_m=bm_in, keep_dense=False)
+                ci = x.logical_shape[-1] if isinstance(x, engine.EventStream) \
+                    else x.shape[-1]
+                ccfg = conv_base.replace(threshold=0.0).for_conv(ci)
+                if stats is not None:
+                    # twin-free on chain
+                    nzmap, (b, h, w, c) = _pixel_events(x)
+                    touched = _touched_outputs(h, w, layer.k, layer.stride,
+                                               layer.padding)
+                    stats.append(dict(
+                        event_macs=jnp.sum(nzmap * touched[None].astype(
+                            jnp.float32)) * layer.out_ch,
+                        in_events=jnp.sum(nzmap)))
+                acc = engine.conv2d(x, wgt, cfg=ccfg, stride=layer.stride,
+                                    padding=layer.padding)
+                if chain:
+                    # Drop the dense twin at conv→conv boundaries, at
+                    # conv→pool boundaries the event-native pool will
+                    # consume, AND at conv→FC seams the re-tiler serves
+                    # (events-only — instrumentation reads event values,
+                    # never the twin); keep it only where an ineligible
+                    # consumer genuinely reads it densely.
+                    pool_chains = (isinstance(nxt, PoolSpec)
+                                   and engine.pool_ineligible_reason(
+                                       tuple(acc.shape), nxt.k, nxt.stride,
+                                       conv_base) is None)
+                    bm_next = _next_conv_blk_m(nxt, tuple(acc.shape))
+                    keep = not (isinstance(nxt, ConvSpec) or pool_chains
+                                or _fc_chains(nxt, tuple(acc.shape),
+                                              conv_base, bm_next))
+                    if not keep and conv_base.route != "auto":
+                        # Adaptive/forced routing may send the next
+                        # boundary dense; keep the twin so its
+                        # ``dense_nhwc`` is a free read, not a decode.  Same
+                        # decision function the dispatch uses — plan and
+                        # dispatch cannot disagree.
+                        keep = not _next_boundary_route(
+                            nxt, tuple(acc.shape), conv_base,
+                            bm_next).is_event
+                    x = engine.fire_conv(acc, conv_base, keep_dense=keep,
+                                         blk_m=bm_next)
                 else:
-                    x = pooled
-        elif isinstance(layer, FCSpec):
-            # Conv-derived inputs (a chained conv stream, or the round-trip
-            # twin's dense NHWC map) dispatch under the *re-tiled* geometry:
-            # blk_m = 1 and the conv chain's channel-clamped blk_k, so the
-            # twin's encode of the flattened map produces the exact
-            # BlockEvents the re-tiler emits — bitwise equality across the
-            # conv→FC seam, not just allclose (DESIGN.md §12).  FC→FC
-            # boundaries keep the plain cfg (the fire emitted that
-            # geometry).
-            if isinstance(x, engine.EventStream) \
-                    and x.logical_shape is not None:
-                fcfg = cfg.replace(threshold=0.0, blk_m=1, blk_k=x.blk_k)
-            elif not isinstance(x, engine.EventStream) and x.ndim == 4:
-                fcfg = cfg.replace(
-                    threshold=0.0, blk_m=1,
-                    blk_k=min(conv_base.blk_k, max(x.shape[-1], 1)))
-            else:
-                fcfg = cfg.replace(threshold=0.0)
-            flat = x if isinstance(x, engine.EventStream) \
-                else x.reshape(x.shape[0], -1)
-            if stats is not None:
-                in_ev = fc_in_events(flat, fire_cfg.threshold)
-                stats.append(dict(event_macs=in_ev * layer.out,  # Algorithm 2
-                                  in_events=in_ev))
-            acc = engine.linear(flat, wgt, cfg=fcfg)
-            last = layer is spec.layers[-1]
-            if last:
-                x = acc
-            elif chain:
-                x = engine.fire(acc, cfg, keep_dense=False)
-            else:
-                x = fire(acc, fire_cfg)
-            if stats is not None:
-                stats[-1]["out_density"] = _density(x)
-    if isinstance(x, engine.EventStream) and x.logical_shape is not None:
-        return x.dense_nhwc()        # conv-final spec: keep the NHWC view
-    return _dense(x)
+                    x = fire(acc, fire_cfg)      # fire phase == ReLU @ 0
+                if stats is not None:
+                    stats[-1]["out_density"] = _density(x)
+            elif isinstance(layer, PoolSpec):
+                if chain and isinstance(x, engine.EventStream) \
+                        and engine.pool_ineligible_reason(
+                            x, layer.k, layer.stride, conv_base) is None:
+                    # Event-native pool (DESIGN.md §7): segment max over
+                    # the stream's events, re-emitted at the granularity
+                    # the consumer wants — conv→pool→conv stays
+                    # events-only (no twin, no re-encode).  The pooled twin
+                    # is kept only when the FC head (or the network output)
+                    # reads it densely.
+                    c = x.logical_shape[-1]
+                    oh = (x.logical_shape[1] - layer.k) // layer.stride + 1
+                    pw = (x.logical_shape[2] - layer.k) // layer.stride + 1
+                    pooled_shape = (x.logical_shape[0], oh, pw, c)
+                    # Emitted granularity from the consumer (same rule as
+                    # the conv fire): strips for a strip-eligible conv *or*
+                    # a window-eligible next pool, pixels otherwise.
+                    pcfg = conv_base.for_pool(c).replace(
+                        blk_m=_next_conv_blk_m(nxt, pooled_shape))
+                    keep_pool = not (isinstance(nxt, ConvSpec)
+                                     or _fc_chains(nxt, pooled_shape,
+                                                   conv_base, pcfg.blk_m))
+                    if not keep_pool and conv_base.route != "auto":
+                        keep_pool = not _next_boundary_route(
+                            nxt, pooled_shape, conv_base,
+                            pcfg.blk_m).is_event
+                    x = engine.maxpool2d(x, layer.k, layer.stride, cfg=pcfg,
+                                         keep_dense=keep_pool)
+                else:
+                    pooled = max_pool_nhwc(_dense_nhwc(x), layer.k,
+                                           layer.stride)
+                    if chain and isinstance(nxt, ConvSpec):
+                        # Dense-pool fallback (round-trip twin, or a stream
+                        # the event pool cannot consume): re-encode at the
+                        # granularity the next conv consumes.
+                        x = engine.EventStream.encode_nhwc(
+                            pooled, blk_k=conv_base.blk_k,
+                            blk_m=_next_conv_blk_m(nxt, tuple(pooled.shape)),
+                            keep_dense=False)
+                    else:
+                        x = pooled
+            elif isinstance(layer, FCSpec):
+                # Conv-derived inputs (a chained conv stream, or the
+                # round-trip twin's dense NHWC map) dispatch under the
+                # *re-tiled* geometry: blk_m = 1 and the conv chain's
+                # channel-clamped blk_k, so the twin's encode of the
+                # flattened map produces the exact BlockEvents the re-tiler
+                # emits — bitwise equality across the conv→FC seam, not
+                # just allclose (DESIGN.md §12).  FC→FC boundaries keep the
+                # plain cfg (the fire emitted that geometry).
+                if isinstance(x, engine.EventStream) \
+                        and x.logical_shape is not None:
+                    fcfg = cfg.replace(threshold=0.0, blk_m=1, blk_k=x.blk_k)
+                elif not isinstance(x, engine.EventStream) and x.ndim == 4:
+                    fcfg = cfg.replace(
+                        threshold=0.0, blk_m=1,
+                        blk_k=min(conv_base.blk_k, max(x.shape[-1], 1)))
+                else:
+                    fcfg = cfg.replace(threshold=0.0)
+                flat = x if isinstance(x, engine.EventStream) \
+                    else x.reshape(x.shape[0], -1)
+                if stats is not None:
+                    in_ev = fc_in_events(flat, fire_cfg.threshold)
+                    stats.append(dict(event_macs=in_ev * layer.out,  # Alg. 2
+                                      in_events=in_ev))
+                acc = engine.linear(flat, wgt, cfg=fcfg)
+                last = layer is spec.layers[-1]
+                if last:
+                    x = acc
+                elif chain:
+                    x = engine.fire(acc, cfg, keep_dense=False)
+                else:
+                    x = fire(acc, fire_cfg)
+                if stats is not None:
+                    stats[-1]["out_density"] = _density(x)
+    with engine.scope("out"):
+        if isinstance(x, engine.EventStream) and x.logical_shape is not None:
+            return x.dense_nhwc()    # conv-final spec: keep the NHWC view
+        return _dense(x)
 
 
 def cnn_forward(params, x: jax.Array, spec: CNNSpec, *, mnf: bool = True,

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from repro import engine
 from repro.core.fire import FireConfig, fire
-from repro.models.cnn import FCSpec, fc_in_events
+from repro.models.cnn import FCSpec, fc_in_events, layer_scope
 
 __all__ = ["MLPSpec", "LENET_300_100", "MLP_MINI", "init_mlp_params",
            "mlp_forward", "make_mlp_forward", "make_mlp_pipeline",
@@ -153,18 +153,19 @@ def _forward(params, x, spec: MLPSpec, *, fire_cfg: FireConfig,
     fcfg = cfg.replace(threshold=0.0)
     layers = spec.layers
     for i, (layer, wgt) in enumerate(zip(layers, params)):
-        if stats is not None:
-            in_ev = fc_in_events(x, fire_cfg.threshold)
-            stats.append(dict(event_macs=in_ev * layer.out,  # Algorithm 2
-                              in_events=in_ev))
-        acc = engine.linear(x, wgt, cfg=fcfg)
-        last = i == len(layers) - 1
-        if last:
-            x = acc
-        elif chain:
-            x = engine.fire(acc, cfg, keep_dense=False)
-        else:
-            x = fire(acc, fire_cfg)
+        with engine.scope(layer_scope(i, layer)):
+            if stats is not None:
+                in_ev = fc_in_events(x, fire_cfg.threshold)
+                stats.append(dict(event_macs=in_ev * layer.out,  # Algorithm 2
+                                  in_events=in_ev))
+            acc = engine.linear(x, wgt, cfg=fcfg)
+            last = i == len(layers) - 1
+            if last:
+                x = acc
+            elif chain:
+                x = engine.fire(acc, cfg, keep_dense=False)
+            else:
+                x = fire(acc, fire_cfg)
     return x
 
 

@@ -51,7 +51,6 @@ class Request:
     image: Any                         # (H, W, C) array
     submit_time: float = 0.0
     arrival_tick: int = -1
-    completion_tick: int = -1
     bucket: int = 0
     latency_s: float = 0.0
     result: Optional[Any] = None

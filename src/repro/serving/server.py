@@ -19,6 +19,16 @@ The three invariants the tier is built around, each enforced or measured:
     traces every bucket's pipeline under ``engine.trace_dispatch``; an
     eligible boundary reporting ``fallback_decode`` is a serving bug, not
     a slow path (CI-fatal in the smoke loop).
+
+The tier names its work to the profiler (``engine.trace.span``), per tick
+and per batch, never per request: ``mnf.serve.tick`` (stats ``tick``,
+``queued``) holds one ``mnf.serve.batch`` per bucket batch (``bucket``,
+``rows``, ``rid0``/``rid1``, ``wait_ms``: how long its oldest row queued),
+which holds ``pad``, ``place``, ``dispatch``, ``wait`` (the device),
+``fetch`` and ``complete``; ``mnf.serve.compile`` marks every executable
+load or compile.  ``op_layers`` maps a bucket's compiled instructions to
+the network layer scopes (``mnf.L03.conv``) that the device time in a
+trace is attributed by.
 """
 from __future__ import annotations
 
@@ -31,6 +41,7 @@ import numpy as np
 
 from repro import engine as mnf_engine
 from repro.core.fire import FireConfig
+from repro.engine.trace import op_scopes, span
 from repro.launch.steps import make_cnn_serve_step
 from repro.serving.aot import (aot_compile, load_executable,
                                save_executable, snapshot_key)
@@ -65,6 +76,16 @@ class ServeEngineConfig:
     cache_dir: str | None = None
     aot_warmup: bool = True
     max_batches_per_tick: int | None = None
+
+
+def _memory_plan(compiled) -> dict:
+    """An executable's device memory plan, bytes per device."""
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return {}
+    return dict(argument_bytes=int(mem.argument_size_in_bytes),
+                output_bytes=int(mem.output_size_in_bytes),
+                temp_bytes=int(mem.temp_size_in_bytes))
 
 
 def percentile(values: list, q: float) -> float:
@@ -151,19 +172,23 @@ class ServeEngine:
             key = self._snapshot_key(bucket)
             if self.cfg.cache_dir:
                 t0 = time.perf_counter()
-                restored = load_executable(self.cfg.cache_dir, key)
+                with span("serve.compile", bucket=bucket, source="snapshot"):
+                    restored = load_executable(self.cfg.cache_dir, key)
                 if restored is not None:
                     self._exec[bucket] = restored
                     self.snapshot_hits += 1
                     self.warmup_s[bucket] = dict(
-                        load_s=round(time.perf_counter() - t0, 4))
+                        load_s=round(time.perf_counter() - t0, 4),
+                        **_memory_plan(restored))
                     return restored
             self.recompiles += 1
-            compiled, lower_s, compile_s = aot_compile(
-                plan.fn, plan.arg_specs, fresh=bool(self.cfg.cache_dir))
+            with span("serve.compile", bucket=bucket, source="compile"):
+                compiled, lower_s, compile_s = aot_compile(
+                    plan.fn, plan.arg_specs, fresh=bool(self.cfg.cache_dir))
             self._exec[bucket] = compiled
             self.warmup_s[bucket] = dict(lower_s=round(lower_s, 4),
-                                         compile_s=round(compile_s, 4))
+                                         compile_s=round(compile_s, 4),
+                                         **_memory_plan(compiled))
             if self.cfg.cache_dir:
                 save_executable(compiled, self.cfg.cache_dir, key)
         return self._exec[bucket]
@@ -171,10 +196,30 @@ class ServeEngine:
     def warm(self) -> dict:
         """AOT-compile every bucket (startup warmup; persistent-cache hits
         make a restarted replica's warmup a disk read).  Returns per-bucket
-        lower/compile seconds."""
+        lower/compile (or snapshot load) seconds and the executable's
+        memory plan: ``argument_bytes``, ``output_bytes``, ``temp_bytes``
+        per device."""
         for b in self.cfg.buckets:
             self._compiled(b)
         return self.warmup_s
+
+    def op_layers(self, bucket: int) -> dict[str, str]:
+        """Instruction name (``mnf_event_matmul.349``, ``fusion.6``) ->
+        network layer scope (``mnf.L03.conv``, ``mnf.out``) of the bucket's
+        compiled step, read off its HLO text: the names a profiler trace
+        gives each device op.  Built on demand, never on the serving path.
+        """
+        layers = op_scopes(self._compiled(bucket).as_text())
+        if not layers:
+            # JAX's persistent compile cache keys a module without its
+            # debug info, so the executable may have been read back from an
+            # entry that a build without layer scopes wrote: compile the
+            # same module afresh to read them.
+            plan = self.plans[bucket]
+            self.recompiles += 1
+            compiled, _, _ = aot_compile(plan.fn, plan.arg_specs, fresh=True)
+            layers = op_scopes(compiled.as_text())
+        return layers
 
     def boundary_report(self, bucket: int | None = None) -> dict:
         """Abstract-trace one bucket's pipeline: chained/pool/fallback
@@ -223,35 +268,51 @@ class ServeEngine:
         """Drain this tick's queue through the compiled buckets.
 
         Routing, padding, execution, unpadding; completions carry
-        per-request latency (submit → logits ready).  Returns the
+        per-request latency (submit → logits on the host).  Returns the
         requests completed this tick, in FIFO order.
         """
         t_tick0 = time.perf_counter()
         done: list[Request] = []
         budget = self.batcher.max_batches_per_tick
         batches = 0
-        while budget is None or batches < budget:
-            batch = self.batcher.next_batch()
-            if batch is None:
-                break
-            bucket, reqs = batch
-            batches += 1
-            x = pad_bucket([r.image for r in reqs], bucket)
-            y = self._compiled(bucket)(self.params, self._place(bucket, x))
-            y = jax.block_until_ready(y)
-            now = time.perf_counter()
-            logits = np.asarray(y)[:len(reqs)]      # mask padded rows off
-            for i, r in enumerate(reqs):
-                r.result = logits[i]
-                r.latency_s = now - r.submit_time
-                r.completion_tick = self.batcher.tick
-            if self.ttfr_s is None:
-                self.ttfr_s = now - self._born
-            done.extend(reqs)
+        with span("serve.tick", tick=self.batcher.tick,
+                  queued=self.batcher.pending()):
+            while budget is None or batches < budget:
+                batch = self.batcher.next_batch()
+                if batch is None:
+                    break
+                bucket, reqs = batch
+                batches += 1
+                self._run_batch(bucket, reqs)
+                done.extend(reqs)
         self.batcher.end_tick()
         self._serve_window += time.perf_counter() - t_tick0
         self.completed.extend(done)
         return done
+
+    def _run_batch(self, bucket: int, reqs: list[Request]) -> None:
+        """Serve one bucket batch and complete its requests."""
+        wait_ms = (time.perf_counter() - reqs[0].submit_time) * 1e3
+        with span("serve.batch", bucket=bucket, rows=len(reqs),
+                  rid0=reqs[0].rid, rid1=reqs[-1].rid, wait_ms=wait_ms):
+            with span("serve.pad"):
+                x = pad_bucket([r.image for r in reqs], bucket)
+            step = self._compiled(bucket)
+            with span("serve.place"):
+                x = self._place(bucket, x)
+            with span("serve.dispatch"):
+                y = step(self.params, x)
+            with span("serve.wait"):
+                y = jax.block_until_ready(y)
+            with span("serve.fetch"):
+                logits = np.asarray(y)[:len(reqs)]  # mask padded rows off
+            now = time.perf_counter()
+            with span("serve.complete"):
+                for i, r in enumerate(reqs):
+                    r.result = logits[i]
+                    r.latency_s = now - r.submit_time
+            if self.ttfr_s is None:
+                self.ttfr_s = now - self._born
 
     # -- reporting -----------------------------------------------------------
 
