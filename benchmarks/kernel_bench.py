@@ -373,7 +373,7 @@ def cnn_chain_rows(out_path: str = "BENCH_engine.json", *, smoke=False,
     from repro.models.cnn import (ALEXNET, ALEXNET_DS, ALEXNET_FF, MINI_S4,
                                   VGG16, VGG16_DS,
                                   ConvSpec, FCSpec, FireConfig, PoolSpec,
-                                  _input_stream_blk_m, _layer_cfg,
+                                  _conv_base, _input_stream_blk_m, _layer_cfg,
                                   _trace_shapes, chain_boundary_summary,
                                   cnn_forward, init_cnn_params,
                                   make_cnn_pipeline)
@@ -443,9 +443,8 @@ def cnn_chain_rows(out_path: str = "BENCH_engine.json", *, smoke=False,
         # Strip layers carry their compacted-vs-worst-case subtap counts
         # (dead straddle parts dropped at plan time).
         shapes = _trace_shapes(spec)
-        conv_base = _layer_cfg(None, mnf=True, fire_cfg=FireConfig())
-        conv_base = conv_base.replace(blk_m=1,
-                                      blk_k=min(8, conv_base.blk_k))
+        conv_base = _conv_base(_layer_cfg(None, mnf=True,
+                                          fire_cfg=FireConfig()))
         per_layer, stream_in, dense_head_launches = [], False, 0
         for i, layer in enumerate(spec.layers):
             h_in, w_in, c_in = shapes[i]

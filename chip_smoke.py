@@ -92,7 +92,7 @@ def pool_phase(cfg) -> None:
 
         def pooled(x, k=k, s=s, blk_m=blk_m):
             stream = engine.EventStream.encode_nhwc(
-                x, blk_k=8, blk_m=blk_m, keep_dense=False)
+                x, blk_k=cfg.blk_k, blk_m=blk_m, keep_dense=False)
             return engine.maxpool2d(stream, k, s, cfg=cfg.for_pool(
                 shape[-1])).dense_nhwc()
 

@@ -35,6 +35,7 @@ __all__ = [
     "encode_scalar_events",
     "count_nonzero_events",
     "block_occupancy",
+    "channel_blk_k",
     "encode_block_events",
     "decode_block_events",
     "gather_row_groups",
@@ -641,6 +642,23 @@ def pool_strip_map(logical_shape: tuple, k: int, stride: int):
                 tap[t] = dy * k + dx
                 t += 1
     return src, live, shift, tap
+
+
+def channel_blk_k(c: int, blk_k: int) -> int:
+    """K-block width of a conv stream over ``c`` channels under a
+    configured ``blk_k`` (DESIGN.md §5): all ``c`` channels in one block
+    when they fit, else the widest multiple of 8 that divides ``c`` and is
+    at most ``blk_k`` — so the conv→FC re-tiler's ``C % blk_k == 0`` holds
+    wherever such a width exists — else ``blk_k``.  The one clamp the
+    conv, pool and fire boundaries, the encoder and the chain planner
+    share, so a stream and its round-trip twin always tile alike.
+    """
+    if c <= blk_k:
+        return max(c, 1)
+    for d in range(blk_k - blk_k % 8, 0, -8):
+        if c % d == 0:
+            return d
+    return blk_k
 
 
 def retile_ineligible_reason(logical_shape: tuple | None, blk_m: int,

@@ -19,9 +19,12 @@ from repro.engine import trace
 from repro.engine.config import EngineConfig
 from repro.engine.registry import dispatch, get_backend, list_backends
 from repro.engine.stream import EventStream
-from repro.kernels.event_conv.kernel import event_conv_launches
-from repro.kernels.event_matmul.kernel import event_matmul_launches
-from repro.kernels.event_pool.kernel import event_pool_launches
+from repro.kernels.event_conv.kernel import (event_conv_grid_steps,
+                                             event_conv_launches)
+from repro.kernels.event_matmul.kernel import (event_matmul_grid_steps,
+                                               event_matmul_launches)
+from repro.kernels.event_pool.kernel import (event_pool_grid_steps,
+                                             event_pool_launches)
 
 __all__ = ["matmul", "linear", "conv2d", "maxpool2d",
            "pool_ineligible_reason", "route_conv", "route_pool",
@@ -178,12 +181,18 @@ def _with_route(dec, route: str):
     return dataclasses.replace(dec, route=route)
 
 
-def _route_fields(dec: "xover.RouteDecision", shape_class: str) -> dict:
+def _route_fields(dec: "xover.RouteDecision", shape_class: str,
+                  **tiling) -> dict:
     """The per-decision trace fields every boundary record carries
-    (satellite contract pinned by tests/test_routing.py)."""
+    (satellite contract pinned by tests/test_routing.py).  Conv, pool and
+    linear records add their tiling: ``blk_k``, the incoming stream's
+    K-block width, and ``grid_steps``, the static total of Pallas grid
+    steps the event route takes over all its launches and taps (0 on a
+    dense route, which launches no event kernel on the stream) — counted
+    from shapes at trace time, whatever backend runs."""
     return dict(route=dec.route, est_event_cost=dec.est_event_cost,
                 est_dense_cost=dec.est_dense_cost, occupancy=dec.occupancy,
-                route_source=dec.source, shape_class=shape_class)
+                route_source=dec.source, shape_class=shape_class, **tiling)
 
 
 def matmul(a: jax.Array, w: jax.Array,
@@ -238,10 +247,13 @@ def linear(x, w: jax.Array, b: jax.Array | None = None,
             bsz, hh, ww, cc = x.logical_shape
             m, k = bsz, hh * ww * cc
         name = cfg.resolve_backend()
-        dec = route_linear(m, k, w.shape[-1], cfg,
-                           eligible=retile_reason is None)
-        fields = _route_fields(dec,
-                               xover.linear_shape_class(m, k, w.shape[-1]))
+        n = w.shape[-1]
+        dec = route_linear(m, k, n, cfg, eligible=retile_reason is None)
+        steps = event_matmul_grid_steps(
+            x.events.values.shape[0], x.events.capacity, n,
+            cfg.blk_n) if dec.is_event else 0
+        fields = _route_fields(dec, xover.linear_shape_class(m, k, n),
+                               blk_k=x.blk_k, grid_steps=steps)
         if retiled:
             fields["retile"] = True
         if dec.is_event:
@@ -309,10 +321,13 @@ def conv2d(x, w: jax.Array, b: jax.Array | None = None,
                           jnp.promote_types(x.events.values.dtype, w.dtype))
             return y if b is None else y + b
         k = w.shape[0]
+        co = w.shape[-1]
+        e = x.events.capacity
         if is_conv_stream:
             dec = route_conv(x.logical_shape, w.shape, cfg, stride=stride,
                              padding=padding, blk_m=x.blk_m)
-            fields = _route_fields(dec, f"k{k}s{stride}")
+            fields = _route_fields(dec, f"k{k}s{stride}", blk_k=x.blk_k,
+                                   grid_steps=0)
             if dec.route == "strip":
                 # Compacted inner-grid accounting rides every strip record
                 # (the BENCH per-layer compaction column reads these).
@@ -321,7 +336,9 @@ def conv2d(x, w: jax.Array, b: jax.Array | None = None,
                 src = ev.strip_tap_map(x.logical_shape, k, padding,
                                        stride)[0]
                 launches = event_conv_launches(
-                    src, x.events.capacity, int8=x.qparams is not None)
+                    src, e, int8=x.qparams is not None)
+                fields["grid_steps"] = event_conv_grid_steps(src, e, co,
+                                                             cfg.blk_n)
                 trace.record(op="conv2d", backend=name, chained=True,
                              strip=True, launches=launches, stride=stride,
                              subtaps=subtaps, subtaps_worst=subtaps_worst,
@@ -335,7 +352,9 @@ def conv2d(x, w: jax.Array, b: jax.Array | None = None,
                 rows = bsz * conv_out_size(h, k, stride, padding) \
                     * conv_out_size(wd, k, stride, padding)
                 launches = k * k * event_matmul_launches(
-                    rows, x.events.capacity, int8=x.qparams is not None)
+                    rows, e, int8=x.qparams is not None)
+                fields["grid_steps"] = k * k * event_matmul_grid_steps(
+                    rows, e, co, cfg.blk_n)
                 trace.record(op="conv2d", backend=name, chained=True,
                              launches=launches, **fields)
                 return get_backend("conv2d_events", name)(x, w, b, cfg,
@@ -364,7 +383,8 @@ def conv2d(x, w: jax.Array, b: jax.Array | None = None,
                 dense_macs=float(x.shape[0] * x.shape[1] * w.shape[-1]),
                 avg_touched=1.0, c_out=w.shape[-1], backend=name)
             trace.record(op="conv2d", backend=name, fallback_decode=True,
-                         **_route_fields(dec, f"k{k}s{stride}"))
+                         **_route_fields(dec, f"k{k}s{stride}",
+                                         blk_k=x.blk_k, grid_steps=0))
             x = x.dense()
     return dispatch("conv2d", cfg)(x, w, b, cfg, stride, padding)
 
@@ -450,7 +470,7 @@ def maxpool2d(x, k: int, stride: int | None = None,
                 avg_touched=1.0, c_out=x.shape[1], backend=name)
         fields = _route_fields(
             dec, f"k{k}s{stride}c{x.logical_shape[3]}" if shape_ok
-            else f"k{k}s{stride}")
+            else f"k{k}s{stride}", blk_k=x.blk_k, grid_steps=0)
         if reason is None:
             b, h, w, c = x.logical_shape
             oh = (h - k) // stride + 1
@@ -477,6 +497,8 @@ def maxpool2d(x, k: int, stride: int | None = None,
                                           x.blk_m)[0])
                 launches = event_pool_launches(src, x.events.capacity,
                                                window=window)
+                fields["grid_steps"] = event_pool_grid_steps(
+                    src, x.events.capacity)
                 trace.record(op="maxpool2d", backend=name, chained=True,
                              pool_events=True, launches=launches, **fields)
                 rows = get_backend(op_name, name)(x, k, stride, cfg)
@@ -727,7 +749,7 @@ def fire_conv(acc: jax.Array, cfg: EngineConfig = _DEFAULT, *,
         (blk_m, acc.shape, "strip streams need blk_m == STRIP_W and "
                            "W % STRIP_W == 0")
     acc2 = acc.reshape(b * h * w, c)
-    c2 = cfg.replace(blk_m=blk_m).for_width(*acc2.shape)
+    c2 = cfg.for_conv(c).replace(blk_m=blk_m).for_width(*acc2.shape)
     signed = cfg.magnitude or cfg.signed
     if 0 in acc2.shape:
         s = EventStream.empty(acc2.shape, blk_m=c2.blk_m, blk_k=c2.blk_k,

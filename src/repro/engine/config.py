@@ -156,8 +156,9 @@ class EngineConfig:
         the event-row granularity (strip vs pixel tiling — DESIGN.md §6).
 
         Conv taps contract over CI, so a ``blk_k`` wider than CI would only
-        pad; every conv backend applies this one clamp (the shared twin of
-        ``for_width`` for the channel axis).
+        pad; every conv backend applies this one clamp
+        (``core.events.channel_blk_k``: CI itself when it fits, else a
+        width that divides CI where one exists).
 
         With ``width`` and ``k`` given, also resolves ``blk_m``: STRIP_W
         (8-pixel row strips — the fused-tap kernel's granularity) when the
@@ -168,9 +169,10 @@ class EngineConfig:
         raises ``ValueError`` naming the failing rule instead.
         ``strips=False`` forces pixel tiling.
         """
-        from repro.core.events import STRIP_W, strip_ineligible_reason
+        from repro.core.events import (STRIP_W, channel_blk_k,
+                                       strip_ineligible_reason)
 
-        cfg = dataclasses.replace(self, blk_k=min(self.blk_k, max(ci, 1)))
+        cfg = dataclasses.replace(self, blk_k=channel_blk_k(ci, self.blk_k))
         if width is None and k is None and strips is None:
             return cfg
         if strips is False:
@@ -202,9 +204,10 @@ class EngineConfig:
         consumer geometry the pooled stream stays pixel-granular
         (DESIGN.md §7).
         """
-        from repro.core.events import STRIP_W, strip_ineligible_reason
+        from repro.core.events import (STRIP_W, channel_blk_k,
+                                       strip_ineligible_reason)
 
-        cfg = dataclasses.replace(self, blk_k=min(self.blk_k, max(c, 1)))
+        cfg = dataclasses.replace(self, blk_k=channel_blk_k(c, self.blk_k))
         if width is None or k is None:
             return dataclasses.replace(cfg, blk_m=1)
         reason = strip_ineligible_reason(width, k, stride, padding, co)

@@ -136,7 +136,7 @@ class EventStream:
             (blk_m, x.shape, "strip encoding needs blk_m == STRIP_W and "
                              "W % STRIP_W == 0")
         flat = x.reshape(b * h * w, c)
-        s = cls.encode(flat, blk_m=blk_m, blk_k=min(blk_k, max(c, 1)),
+        s = cls.encode(flat, blk_m=blk_m, blk_k=ev.channel_blk_k(c, blk_k),
                        capacity=capacity, threshold=threshold,
                        keep_dense=keep_dense)
         return dataclasses.replace(s, logical_shape=(b, h, w, c))

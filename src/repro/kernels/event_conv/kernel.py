@@ -56,7 +56,7 @@ from repro.kernels.smem import src_chunks
 
 __all__ = ["event_conv_kernel", "event_conv_pallas",
            "event_conv_int8_kernel", "event_conv_int8_pallas",
-           "event_conv_launches"]
+           "event_conv_launches", "event_conv_grid_steps"]
 
 
 def _shift_rows(a, d, *, row_stride: int, remap: str):
@@ -188,6 +188,14 @@ def event_conv_launches(src: np.ndarray, e: int, *, int8: bool = False) -> int:
     """Pallas launches one fused strip conv issues for plan ``src``
     (G_out × T) over E event slots — what trace records report."""
     return len(_chunks(src, e, 2 if int8 else 0))
+
+
+def event_conv_grid_steps(src: np.ndarray, e: int, n: int,
+                          blk_n: int) -> int:
+    """Grid steps one fused strip conv takes for plan ``src`` (G_out × T)
+    over E event slots against N output channels, over all its launches:
+    the grid is (G_out, N/blk_n, T, E)."""
+    return src.size * -(-n // blk_n) * e
 
 
 def _launch(kernel, name: str, a_vals, a_idx, tap, shift, src, cnt, quant,

@@ -34,7 +34,7 @@ from repro.kernels.smem import plan_chunks
 
 __all__ = ["event_matmul_kernel", "event_matmul_pallas",
            "event_matmul_int8_kernel", "event_matmul_int8_pallas",
-           "event_matmul_launches"]
+           "event_matmul_launches", "event_matmul_grid_steps"]
 
 #: Every in-kernel dot contracts f32 at full precision: the MXU's default
 #: single bf16 pass would round event values and weights, and the 0/1
@@ -151,6 +151,13 @@ def event_matmul_launches(g: int, e: int, *, int8: bool = False) -> int:
     """Pallas launches :func:`event_matmul_pallas` issues for G row groups
     of E events (the SMEM chunk count — what trace records report)."""
     return len(plan_chunks(g, e + 1, fixed_words=2 if int8 else 0))
+
+
+def event_matmul_grid_steps(g: int, e: int, n: int, blk_n: int) -> int:
+    """Grid steps :func:`event_matmul_pallas` takes for G row groups of E
+    event slots against N output columns, over all its launches: the grid
+    is (G, N/blk_n, E) and the SMEM split only divides G."""
+    return g * -(-n // blk_n) * e
 
 
 @functools.partial(jax.jit, static_argnames=("blk_n", "interpret",

@@ -41,7 +41,7 @@ from repro.kernels.smem import src_chunks
 
 __all__ = ["event_pool_kernel", "event_pool_pallas",
            "event_pool_window_kernel", "event_pool_window_pallas",
-           "event_pool_launches"]
+           "event_pool_launches", "event_pool_grid_steps"]
 
 
 def event_pool_kernel(row_ref, src_ref, cnt_ref, a_idx_ref,
@@ -98,6 +98,13 @@ def event_pool_launches(src: np.ndarray, e: int, *,
     """Pallas launches one pool layer issues for plan ``src`` (P_out or
     G_out rows × T taps) over E event slots — what trace records report."""
     return len(_chunks(src, e, window=window))
+
+
+def event_pool_grid_steps(src: np.ndarray, e: int) -> int:
+    """Grid steps one pool layer takes for plan ``src`` (P_out or G_out
+    rows × T taps) over E event slots, over all its launches: the grid is
+    (rows, T, E) for the per-event and the window-major kernel alike."""
+    return src.size * e
 
 
 def event_pool_pallas(a_vals: jax.Array, a_idx: jax.Array, row: np.ndarray,

@@ -281,7 +281,7 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
     without tracing.
     """
     cfg = _layer_cfg(engine_cfg, mnf=True, fire_cfg=fire_cfg)
-    conv_base = cfg.replace(blk_m=1, blk_k=min(8, cfg.blk_k))
+    conv_base = _conv_base(cfg)
     shapes = _trace_shapes(spec)
     out = dict(conv=0, fc=0, pool=0, pool_events=0, densify=0,
                input_encode=0, retile=0, routes=[])
@@ -328,8 +328,7 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
                 reason = None
                 if conv_stream_in:
                     reason = engine.retile_ineligible_reason(
-                        (batch, h, w, c), blk_m,
-                        min(conv_base.blk_k, max(c, 1)))
+                        (batch, h, w, c), blk_m, conv_base.for_conv(c).blk_k)
                 dec = engine.route_linear(batch, kf, layer.out, cfg,
                                           eligible=reason is None)
                 rec = dict(op="linear", route=dec.route,
@@ -370,6 +369,24 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
                 out["densify"] += 1
                 conv_stream_in = False
     return out
+
+
+def _conv_base(cfg: engine.EngineConfig) -> engine.EngineConfig:
+    """The conv chain's dispatch config, shared by ``_forward`` and
+    ``chain_boundary_summary`` (DESIGN.md §5).
+
+    ``blk_m`` stays 1 (pixel rows) so the round-trip twin multiplies
+    identical tiles in identical order as the chained path — bit-for-bit
+    equality, not just allclose; the chained path's strip granularity
+    rides the *stream* instead.  The K block is ``cfg.blk_k`` as each
+    boundary clamps it to its channel count (``core.events.channel_blk_k``:
+    one block of C channels when C <= blk_k, lane-wide blocks above).
+    Narrower blocks would buy no skipping on real maps: after a ReLU conv
+    nearly every 8-channel block of a pixel holds a non-zero (99.6% on
+    AlexNet@224 at 50% element density), so the width only sets the grid
+    — 12-16x more kernel steps at 8 channels than at the lane width.
+    """
+    return cfg.replace(blk_m=1)
 
 
 def _layer_cfg(base: engine.EngineConfig | None, *, mnf: bool,
@@ -460,9 +477,8 @@ def _fc_chains(nxt, out_shape: tuple, cfg: engine.EngineConfig,
     stay events-only (DESIGN.md §12)."""
     if not isinstance(nxt, FCSpec):
         return False
-    blk_k = min(cfg.blk_k, max(out_shape[-1], 1))
-    return engine.retile_ineligible_reason(tuple(out_shape), blk_m,
-                                           blk_k) is None
+    return engine.retile_ineligible_reason(
+        tuple(out_shape), blk_m, cfg.for_conv(out_shape[-1]).blk_k) is None
 
 
 def _pixel_events(x):
@@ -531,15 +547,11 @@ def _forward(params, x, spec: CNNSpec, *, mnf: bool, fire_cfg: FireConfig,
     decode).
     """
     layers = spec.layers
-    # The conv *dispatch* config stays pixel-granular (blk_m == 1) so the
-    # round-trip twin multiplies identical tiles in identical order as the
-    # chained path — bit-for-bit equality, not just allclose (DESIGN.md §5).
-    # The chained path's granularity rides the *stream*: fired streams are
-    # strip-aligned (blk_m == STRIP_W) whenever the consuming layer can ride
-    # the fused-tap kernel, which only interleaves exact zeros into the same
-    # reduction tree, so bitwise equality with the per-tap twin survives
-    # (DESIGN.md §6).
-    conv_base = cfg.replace(blk_m=1, blk_k=min(8, cfg.blk_k))
+    # Fired streams are strip-aligned (blk_m == STRIP_W) whenever the
+    # consuming layer can ride the fused-tap kernel, which only interleaves
+    # exact zeros into the same reduction tree, so bitwise equality with
+    # the per-tap twin survives (DESIGN.md §6).
+    conv_base = _conv_base(cfg)
     for i, (layer, wgt) in enumerate(zip(layers, params)):
         with engine.scope(layer_scope(i, layer)):
             nxt = layers[i + 1] if i + 1 < len(layers) else None
@@ -555,9 +567,8 @@ def _forward(params, x, spec: CNNSpec, *, mnf: bool, fire_cfg: FireConfig,
                                                 conv_base)
                     if bm_in:
                         x = engine.EventStream.encode_nhwc(
-                            x, blk_k=min(conv_base.blk_k,
-                                         max(x.shape[-1], 1)),
-                            blk_m=bm_in, keep_dense=False)
+                            x, blk_k=conv_base.blk_k, blk_m=bm_in,
+                            keep_dense=False)
                 ci = x.logical_shape[-1] if isinstance(x, engine.EventStream) \
                     else x.shape[-1]
                 ccfg = conv_base.replace(threshold=0.0).for_conv(ci)
@@ -656,9 +667,8 @@ def _forward(params, x, spec: CNNSpec, *, mnf: bool, fire_cfg: FireConfig,
                         and x.logical_shape is not None:
                     fcfg = cfg.replace(threshold=0.0, blk_m=1, blk_k=x.blk_k)
                 elif not isinstance(x, engine.EventStream) and x.ndim == 4:
-                    fcfg = cfg.replace(
-                        threshold=0.0, blk_m=1,
-                        blk_k=min(conv_base.blk_k, max(x.shape[-1], 1)))
+                    fcfg = conv_base.replace(threshold=0.0).for_conv(
+                        x.shape[-1])
                 else:
                     fcfg = cfg.replace(threshold=0.0)
                 flat = x if isinstance(x, engine.EventStream) \

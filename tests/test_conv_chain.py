@@ -6,10 +6,12 @@ import numpy as np
 import pytest
 
 from repro import engine
+from repro.core.events import channel_blk_k
 from repro.core.mnf_conv import dense_conv2d
-from repro.models.cnn import (ALEXNET, VGG16, CNNSpec, ConvSpec, FCSpec,
-                              PoolSpec, chain_boundary_summary, cnn_forward,
-                              init_cnn_params, make_cnn_pipeline)
+from repro.models.cnn import (ALEXNET, ALEXNET_FF, VGG16, CNNSpec, ConvSpec,
+                              FCSpec, PoolSpec, chain_boundary_summary,
+                              cnn_forward, init_cnn_params, make_cnn_forward,
+                              make_cnn_pipeline)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -192,6 +194,21 @@ def test_for_conv_clamps_blk_k():
     assert cfg.for_conv(0).blk_k == 1             # degenerate channel depth
 
 
+@pytest.mark.parametrize("c,blk_k,want", [
+    (96, 128, 96),      # C fits: one block of C channels
+    (384, 128, 128),    # lane-wide blocks that divide C
+    (320, 128, 80),     # 128 does not divide 320: widest 8k divisor
+    (300, 128, 128),    # no multiple of 8 divides 300: blk_k, padded
+    (6, 4, 4),          # nothing to search below 8
+    (0, 128, 1),        # degenerate channel depth
+])
+def test_channel_blk_k(c, blk_k, want):
+    """The one K-block clamp of conv and pool streams."""
+    assert channel_blk_k(c, blk_k) == want
+    cfg = engine.EngineConfig(blk_k=blk_k)
+    assert cfg.for_conv(c).blk_k == cfg.for_pool(c).blk_k == want
+
+
 # ---------------------------------------------------------------------------
 # fallback visibility: no more invisible round-trips
 # ---------------------------------------------------------------------------
@@ -240,3 +257,113 @@ def test_conv2d_events_fallback_decodes_with_marker():
     ref = dense_conv2d(x, w, stride=1, padding=1)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5,
                                rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# chain tile geometry: K blocks of min(blk_k, C) channels under the default
+# EngineConfig, read off the dispatch records (DESIGN.md §5)
+# ---------------------------------------------------------------------------
+
+#: AlexNet's channel plan (96, 256, 384, 384, 256) at a CPU size: every
+#: width misses STRIP_W, so every boundary takes the per-tap pixel path
+#: the served AlexNet@224 takes.  Maps: 28 -> 14 -> 6 -> 6 -> 2 -> 2.
+ALEX_LANES = CNNSpec(
+    "alexnet_lanes", 28, 3,
+    (ConvSpec(96, 5, 2, 2), PoolSpec(3, 2), ConvSpec(256, 5, 1, 2),
+     PoolSpec(3, 2), ConvSpec(384, 3, 1, 1), ConvSpec(384, 3, 1, 1),
+     ConvSpec(256, 3, 1, 1), FCSpec(10)), num_classes=10)
+
+#: (op, blk_k, grid_steps) of each stream-consuming boundary of ALEX_LANES
+#: at batch 2 with blk_k = blk_n = 128.  Pixel conv: taps · B·OY·OX ·
+#: ⌈CO/128⌉ · nkb; pool: B·OH·OW · k² · nkb; the re-tiled FC: B · ⌈N/128⌉
+#: · H·W·nkb.  conv1 reads the dense image and writes no record.
+ALEX_LANES_STEPS = [
+    ("maxpool2d", 96, 2 * 6 * 6 * 9 * 1),
+    ("conv2d", 96, 25 * (2 * 6 * 6) * 2 * 1),
+    ("maxpool2d", 128, 2 * 2 * 2 * 9 * 2),
+    ("conv2d", 128, 9 * (2 * 2 * 2) * 3 * 2),
+    ("conv2d", 128, 9 * (2 * 2 * 2) * 3 * 3),
+    ("conv2d", 128, 9 * (2 * 2 * 2) * 2 * 3),
+    ("linear", 128, 2 * 1 * (2 * 2 * 2)),
+]
+
+
+@pytest.mark.parametrize("backend", ["block", "pallas"])
+def test_chain_tiles_at_lane_width(backend):
+    """Under the default EngineConfig every conv and pool stream of an
+    AlexNet-class chain tiles K at min(128, C) channels, each record
+    carries that blk_k and the grid steps of the route, and the chained
+    forward stays bitwise its round-trip twin."""
+    cfg = engine.EngineConfig(backend=backend)
+    params = init_cnn_params(KEY, ALEX_LANES, weight_sparsity=0.5)
+    x = jax.nn.relu(jax.random.normal(KEY, (2, 28, 28, 3)))
+    with engine.trace_dispatch() as recs:
+        y = cnn_forward(params, x, ALEX_LANES, engine_cfg=cfg)
+    assert not any(r.get("fallback_decode") or r.get("decode")
+                   for r in recs)
+    got = [(r["op"], r["blk_k"], r["grid_steps"]) for r in recs
+           if r.get("op") in ("conv2d", "maxpool2d", "linear")]
+    assert got == ALEX_LANES_STEPS
+    yr = cnn_forward(params, x, ALEX_LANES, engine_cfg=cfg, chain=False)
+    assert bool(jnp.all(y == yr)), "chained != round-trip bitwise"
+
+
+def _dispatch_records(spec, batch, cfg):
+    """Dispatch records of one abstract trace of the chained forward."""
+    params = jax.eval_shape(lambda: init_cnn_params(KEY, spec))
+    x = jax.ShapeDtypeStruct(
+        (batch, spec.input_size, spec.input_size, spec.in_ch), jnp.float32)
+    with engine.trace_dispatch() as recs:
+        jax.eval_shape(make_cnn_forward(spec, engine_cfg=cfg), params, x)
+    return recs
+
+
+#: A last conv whose 320 channels the default 128-wide block does not
+#: divide: the chain tiles it at 80 so the conv→FC seam stays re-tileable.
+RAGGED = CNNSpec("ragged320", 9, 3,
+                 (ConvSpec(64, 3, 1, 1), ConvSpec(320, 3, 1, 1),
+                  PoolSpec(3, 3), FCSpec(10)), num_classes=10)
+
+
+@pytest.mark.parametrize("spec", [ALEXNET, VGG16, ALEXNET_FF, RAGGED],
+                         ids=lambda s: s.name)
+def test_chain_boundary_summary_matches_dispatch(spec):
+    """At the spec's own input size (224 and 256 for the paper's nets) and
+    the default EngineConfig, the planner's summary shows no densify point
+    and no fallback, and its routes are the dispatch's routes, boundary for
+    boundary — one conv_base behind both."""
+    cfg = engine.EngineConfig()
+    summary = chain_boundary_summary(spec, batch=1, engine_cfg=cfg)
+    assert summary["densify"] == 0 and summary["retile"] == 1
+    assert not any("reason" in r for r in summary["routes"])
+    recs = _dispatch_records(spec, 1, cfg)
+    assert not any(r.get("fallback_decode") or r.get("decode")
+                   for r in recs)
+    keys = ("op", "route", "shape_class", "occupancy")
+    planned = [tuple(r[k] for k in keys) + (r["source"],)
+               for r in summary["routes"]]
+    dispatched = [tuple(r[k] for k in keys) + (r["route_source"],)
+                  for r in recs if r.get("route") is not None]
+    assert planned == dispatched
+
+
+def test_alexnet224_dispatch_grid_steps_match_step_table():
+    """AlexNet@224 at bucket 8, default EngineConfig: the records carry
+    the per-layer K blocks and grid steps of the step model (PERF.md §5).
+    At 8-channel blocks conv2..conv5 took 3.50 M, 1.17 M, 1.75 M and
+    1.17 M steps, pool1 0.63 M and fc6 0.29 M."""
+    recs = _dispatch_records(ALEXNET, 8, engine.EngineConfig())
+    got = [(r["op"], r["blk_k"], r["grid_steps"]) for r in recs
+           if r.get("op") in ("conv2d", "maxpool2d", "linear")]
+    assert got == [
+        ("maxpool2d", 96, 8 * 27 * 27 * 9 * 1),      # pool1   52 488
+        ("conv2d", 96, 25 * 5832 * 2 * 1),           # conv2  291 600
+        ("maxpool2d", 128, 8 * 13 * 13 * 9 * 2),     # pool2   24 336
+        ("conv2d", 128, 9 * 1352 * 3 * 2),           # conv3   73 008
+        ("conv2d", 128, 9 * 1352 * 3 * 3),           # conv4  109 512
+        ("conv2d", 128, 9 * 1352 * 2 * 3),           # conv5   73 008
+        ("maxpool2d", 128, 8 * 6 * 6 * 9 * 2),       # pool3    5 184
+        ("linear", 128, 8 * 32 * (6 * 6 * 2)),       # fc6     18 432
+        ("linear", 128, 1 * 32 * 32),                # fc7
+        ("linear", 128, 1 * 8 * 32),                 # fc8
+    ]

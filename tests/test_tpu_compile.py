@@ -48,11 +48,12 @@ def _compile(fn, sharding, *shapes):
 
 
 def test_fire_compact_conv1_width(one_chip):
-    """conv1's fire: (55·55·B, 96) accumulator, (1, 8) event tiles."""
+    """conv1's fire: (55·55·B, 96) accumulator, one (1, 96) event tile a
+    pixel (the chain's K block is min(blk_k, C))."""
     m = 55 * 55 * BATCH
 
     def fire(acc):
-        return fire_compact_pallas(acc, blk_m=1, blk_k=8, interpret=False)
+        return fire_compact_pallas(acc, blk_m=1, blk_k=96, interpret=False)
 
     assert "tpu_custom_call" in _compile(fire, one_chip,
                                          ((m, 96), jnp.float32))
@@ -87,11 +88,30 @@ def test_event_matmul_conv1_tap(one_chip):
         ((g,), jnp.int32), ((3, 128), jnp.float32))
 
 
+@pytest.mark.parametrize("h,c", [(27, 256), (13, 256)],
+                         ids=["pool2", "pool3"])
+def test_event_pool_wide(one_chip, h, c):
+    """pool2 / pool3: 3x3/2 over a 256-channel pixel stream, two 128-wide
+    K blocks a pixel — (1, 2, 128) out blocks, (2, 128) scratch."""
+    shape = (BATCH, h, h, c)
+    src, row, _ = ev.pool_window_map(shape, 3, 2, 1)
+    g, e = BATCH * h * h, c // 128
+
+    def pool(vals, idx, cnt):
+        return event_pool_pallas(vals, idx, row, src, cnt, nkb=e,
+                                 interpret=False)
+
+    assert "tpu_custom_call" in _compile(
+        pool, one_chip, ((g, e, 1, 128), jnp.float32), ((g, e), jnp.int32),
+        (src.shape, jnp.int32))
+
+
 def test_event_pool_pool1(one_chip):
-    """pool1: 3x3/2 over conv1's 55x55x96 pixel stream (12 K blocks)."""
+    """pool1: 3x3/2 over conv1's 55x55x96 pixel stream, one 96-wide K
+    block a pixel."""
     shape = (BATCH, 55, 55, 96)
     src, row, _ = ev.pool_window_map(shape, 3, 2, 1)
-    g, e = BATCH * 55 * 55, 96 // 8
+    g, e = BATCH * 55 * 55, 1
     assert len(plan_chunks(src.shape[0], 3 * src.shape[1], src=src,
                            src_words=e)) > 1     # the SMEM split is exercised
 
@@ -100,21 +120,42 @@ def test_event_pool_pool1(one_chip):
                                  interpret=False)
 
     assert "tpu_custom_call" in _compile(
-        pool, one_chip, ((g, e, 1, 8), jnp.float32), ((g, e), jnp.int32),
+        pool, one_chip, ((g, e, 1, 96), jnp.float32), ((g, e), jnp.int32),
         (src.shape, jnp.int32))
 
 
+@pytest.mark.parametrize("hw,ci,co", [
+    (27, 96, 256),     # conv2: one (1, 96) a-tile, (96, 128) weight tiles
+    (13, 256, 384),    # conv3: two (1, 128) a-tiles a pixel
+    (13, 384, 384),    # conv4: three
+    (13, 384, 256),    # conv5
+], ids=["conv2", "conv3", "conv4", "conv5"])
+def test_event_matmul_conv_tap(one_chip, hw, ci, co):
+    """One per-tap event matmul of a chained conv: B·H·W pixel row groups
+    of min(128, CI)-wide K blocks against the tap's (CI, CO) weights."""
+    g, bk = BATCH * hw * hw, min(128, ci)
+    e = ci // bk
+
+    def tap(vals, idx, cnt, w):
+        return event_matmul_pallas(vals, idx, cnt, w, blk_n=128,
+                                   interpret=False)
+
+    assert "tpu_custom_call" in _compile(
+        tap, one_chip, ((g, e, 1, bk), jnp.float32), ((g, e), jnp.int32),
+        ((g,), jnp.int32), ((ci, co), jnp.float32))
+
+
 def test_event_matmul_fc6(one_chip):
-    """fc6: the re-tiled 9216-wide conv stream (1152 K blocks of 8) into
+    """fc6: the re-tiled 9216-wide conv stream (72 K blocks of 128) into
     4096 outputs."""
-    e = 9216 // 8
+    e = 9216 // 128
 
     def fc(vals, idx, cnt, w):
         return event_matmul_pallas(vals, idx, cnt, w, blk_n=128,
                                    interpret=False)
 
     assert "tpu_custom_call" in _compile(
-        fc, one_chip, ((BATCH, e, 1, 8), jnp.float32),
+        fc, one_chip, ((BATCH, e, 1, 128), jnp.float32),
         ((BATCH, e), jnp.int32), ((BATCH,), jnp.int32),
         ((9216, 4096), jnp.float32))
 
