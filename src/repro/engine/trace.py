@@ -14,10 +14,14 @@ and benchmarks can assert *where* densification happens::
 Records are appended at Python dispatch time, which under ``jax.jit`` means
 trace time: the counts describe the compiled graph's structure (how many
 decode ops it contains), which is exactly the per-boundary accounting the
-benchmarks report.  Conv dispatches additionally mark the tiling they
-rode — ``strip=True, launches=1`` for the fused strip kernel vs
-``launches=k*k`` for the per-tap path — so grid/launch accounting and the
-strip-degradation CI guard read straight off the records.  Nesting is
+benchmarks report.  Every record carries ``layer``: the index ``i`` of
+the innermost ``mnf.L<i>.<kind>`` scope it was traced under (None outside
+every layer scope), so a record names the network layer whose device time
+the profiler attributes to that scope.  Conv dispatches additionally mark
+the tiling they rode — ``strip=True, launches=1`` for the fused strip
+kernel vs ``launches=k*k`` for the per-tap path — so grid/launch
+accounting and the strip-degradation CI guard read straight off the
+records.  Nesting is
 supported; each context sees every record emitted while it is active.
 
 The same module names the program to the profiler.  ``span`` marks a
@@ -42,16 +46,20 @@ __all__ = ["record", "trace_dispatch", "span", "scope", "op_scopes"]
 PREFIX = "mnf."
 
 _SINKS: list[list] = []
+#: Indices of the layer scopes open now, innermost last.
+_LAYERS: list[int] = []
+_LAYER_NAME = re.compile(r"L(\d+)\.")
 
 
 def record(**fields) -> None:
-    """Append one dispatch record to every active ``trace_dispatch`` context.
+    """Append one dispatch record to every active ``trace_dispatch`` context,
+    with ``layer`` the innermost open layer scope's index.
 
     No-op (and allocation-free) when no context is active — safe to call on
     every hot-path dispatch.
     """
     if _SINKS:
-        rec = dict(fields)
+        rec = dict(fields, layer=_LAYERS[-1] if _LAYERS else None)
         for sink in _SINKS:
             sink.append(rec)
 
@@ -73,9 +81,19 @@ def span(name: str, **stats):
     return jax.profiler.TraceAnnotation(PREFIX + name, **stats)
 
 
+@contextlib.contextmanager
 def scope(name: str):
-    """A named scope ``mnf.<name>`` over the ops traced inside it."""
-    return jax.named_scope(PREFIX + name)
+    """A named scope ``mnf.<name>`` over the ops traced inside it; a layer
+    scope (``L03.conv``) also sets the ``layer`` of the records made in it."""
+    m = _LAYER_NAME.match(name)
+    with jax.named_scope(PREFIX + name):
+        if m:
+            _LAYERS.append(int(m.group(1)))
+        try:
+            yield
+        finally:
+            if m:
+                _LAYERS.pop()
 
 
 # One HLO instruction line: ``[ROOT] %name = <shape> <opcode>(<operands>)...``.

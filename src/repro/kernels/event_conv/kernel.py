@@ -11,10 +11,12 @@ the indirection through BlockSpec index maps:
   a_vals (G_in, E, bm, bk)   strip event tiles, consumed in place — the
                              a-tile DMA'd for grid step (g, ., t, e) is
                              ``a_vals[src[g, t], e]``.
-  ws     (k*k*nkb*bk, N)     tap-stacked weights; the w-tile is block row
+  ws     (k*k*nkb*lane_k, N) tap-stacked weights; the w-tile is block row
                              ``tap[t] * nkb + a_idx[src[g, t], e]`` — the
                              event's direct weight address offset into its
-                             tap's slab.
+                             tap's slab.  ``lane_k`` is bk rounded up to 8
+                             (``lane_blk_k``); the pad rows are zeros that
+                             the dot never reads.
 
 Grid (G_out, N/bn, T, E), T the **compacted** subtap count of the plan
 (``strip_subtap_counts(k, p, stride)[0]``): each tap splits into its
@@ -54,7 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.event_matmul.kernel import HIGHEST
 from repro.kernels.smem import src_chunks
 
-__all__ = ["event_conv_kernel", "event_conv_pallas",
+__all__ = ["event_conv_kernel", "event_conv_pallas", "lane_blk_k",
            "event_conv_int8_kernel", "event_conv_int8_pallas",
            "event_conv_launches", "event_conv_grid_steps"]
 
@@ -80,6 +82,23 @@ def _shift_rows(a, d, *, row_stride: int, remap: str):
     sel = (j == i * row_stride + d).astype(a.dtype)
     return jnp.dot(sel, a, preferred_element_type=jnp.float32,
                    precision=HIGHEST)
+
+
+def lane_blk_k(bk: int) -> int:
+    """Rows of the kernel's weight tile for a stream K block of ``bk``:
+    ``bk`` rounded up to a multiple of 8, the chip's sublane tiling, so that
+    a (lane_k, blk_n) tile of the tap-stacked slab is a legal block.  A
+    strip stream over the 3-channel input image (VGG16's conv1_1) tiles K
+    at 3; each of its K blocks takes 8 slab rows, the last 5 exact zeros
+    (DESIGN.md §6)."""
+    return -(-bk // 8) * 8
+
+
+def _w_tile(w_ref, bk: int):
+    """The weight tile's first ``bk`` rows: the rows an event's K block
+    multiplies.  The zero rows that pad a block to ``lane_blk_k(bk)`` never
+    enter the dot, so the padded head reduces exactly as an unpadded tile."""
+    return w_ref[...] if w_ref.shape[0] == bk else w_ref[:bk, :]
 
 
 def event_conv_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
@@ -108,7 +127,7 @@ def event_conv_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
         a = a_vals_ref[0, 0]                     # (bm, bk) source strip tile
         shifted = _shift_rows(a, shift_ref[t], row_stride=row_stride,
                               remap=remap)
-        tap_acc_ref[...] += jnp.dot(shifted, w_ref[...],
+        tap_acc_ref[...] += jnp.dot(shifted, _w_tile(w_ref, a.shape[-1]),
                                     preferred_element_type=jnp.float32,
                                     precision=HIGHEST)
 
@@ -162,7 +181,7 @@ def event_conv_int8_kernel(tap_ref, shift_ref, src_ref, cnt_ref, a_idx_ref,
         a = (a - zp_ref[0].astype(jnp.float32)) * scale_ref[0]
         shifted = _shift_rows(a, shift_ref[t], row_stride=row_stride,
                               remap=remap)
-        tap_acc_ref[...] += jnp.dot(shifted, w_ref[...],
+        tap_acc_ref[...] += jnp.dot(shifted, _w_tile(w_ref, a.shape[-1]),
                                     preferred_element_type=jnp.float32,
                                     precision=HIGHEST)
 
@@ -203,7 +222,8 @@ def _launch(kernel, name: str, a_vals, a_idx, tap, shift, src, cnt, quant,
     g_in, e, bm, bk = a_vals.shape
     g_out, t_n = src.shape
     rows, n = ws.shape
-    assert rows % (nkb * bk) == 0, (ws.shape, nkb, bk)  # k*k weight slabs
+    lane_k = lane_blk_k(bk)
+    assert rows % (nkb * lane_k) == 0, (ws.shape, nkb, lane_k)  # k*k slabs
     assert n % blk_n == 0, (n, blk_n)
     outs = []
     for r0, r1, s0, s1, src_c in _chunks(src, e, len(quant)):
@@ -214,7 +234,7 @@ def _launch(kernel, name: str, a_vals, a_idx, tap, shift, src, cnt, quant,
                 pl.BlockSpec((1, 1, bm, bk),
                              lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, *_,
                              s0=s0: (s0 + sr[gi * t_n + ti], ei, 0, 0)),
-                pl.BlockSpec((bk, blk_n),
+                pl.BlockSpec((lane_k, blk_n),
                              lambda gi, ni, ti, ei, tp, sh, sr, ct, ai, *_:
                              (tp[ti] * nkb + ai[sr[gi * t_n + ti] * e + ei],
                               ni)),
@@ -248,7 +268,8 @@ def event_conv_pallas(a_vals: jax.Array, a_idx: jax.Array, tap: np.ndarray,
     count (dead straddle parts already dropped — the grid axis is sized by
     the plan handed in, not the worst case); src: static (G_out, T) source
     strip per (output strip, subtap); cnt: (G_out, T) live event counts.
-    ws: tap-stacked weights (k*k*nkb*bk, N), N a multiple of blk_n.
+    ws: tap-stacked weights (k*k*nkb*lane_blk_k(bk), N), N a multiple of
+    blk_n.
     ``row_stride`` is the conv stride: out row i reads src row
     row_stride*i + shift[t].  ``remap`` picks the in-tile row-remap
     lowering ("matmul" | "select" — bit-identical; see the kernel
@@ -259,7 +280,7 @@ def event_conv_pallas(a_vals: jax.Array, a_idx: jax.Array, tap: np.ndarray,
     rows = ws.shape[0]
     t_n = src.shape[1]
     assert remap in ("matmul", "select"), remap
-    assert t_n <= (rows // (nkb * bk)) * \
+    assert t_n <= (rows // (nkb * lane_blk_k(bk))) * \
         (((bm - 1) * row_stride + bm - 1) // bm + 1), \
         (t_n, ws.shape, nkb, bk, row_stride)
     kernel = functools.partial(event_conv_kernel, row_stride=row_stride,

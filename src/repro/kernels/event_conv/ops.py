@@ -17,25 +17,27 @@ from repro.core import events as ev
 from repro.core.mnf_conv import conv_out_size
 from repro.kernels.event_conv.kernel import (event_conv_int8_pallas,
                                              event_conv_launches,
-                                             event_conv_pallas)
+                                             event_conv_pallas, lane_blk_k)
 
 __all__ = ["fused_event_conv2d", "fused_conv_plan"]
 
 
 def _stacked_weights(w: jax.Array, bk: int, nkb: int,
                      blk_n: int) -> jax.Array:
-    """(K, K, CI, CO) -> (k*k*nkb*bk, N): per-tap weight slabs, K-block rows.
+    """(K, K, CI, CO) -> (k*k*nkb*lane_k, N): per-tap weight slabs, K-block
+    rows, ``lane_k = lane_blk_k(bk)``.
 
     Block row ``tap * nkb + kb`` of the result is W[dy, dx] rows
-    [kb*bk, (kb+1)*bk) — the tile the kernel's index map addresses from an
-    event's direct K-block address.
+    [kb*bk, (kb+1)*bk), then ``lane_k - bk`` zero rows — the tile the
+    kernel's index map addresses from an event's direct K-block address.
     """
     k, k2, ci, co = w.shape
     assert k == k2, w.shape
     wf = w.reshape(k * k, ci, co)
     wf = ev.pad_to_block_multiple(wf, bk, 1)
     assert wf.shape[1] == nkb * bk, (wf.shape, nkb, bk)
-    ws = wf.reshape(k * k * nkb * bk, co)
+    wf = wf.reshape(k * k, nkb, bk, co)
+    ws = ev.pad_to_block_multiple(wf, lane_blk_k(bk), 2).reshape(-1, co)
     return ev.pad_to_block_multiple(ws, blk_n, 1)
 
 

@@ -42,6 +42,20 @@ def fused_event_conv2d_ref(stream, w: jax.Array, *, stride: int = 1,
         gat = ev.gather_row_strips(stream.events, jnp.asarray(src[:, t]),
                                    jnp.asarray(live[:, t]), int(shift[t]),
                                    row_stride=stride)
-        acc = acc + block_event_linear_from_events(gat, wtap[int(tap[t])],
-                                                   qparams=stream.qparams)
+        acc = acc + block_event_linear_from_events(
+            _pixel_rows(gat), wtap[int(tap[t])], qparams=stream.qparams)
     return acc
+
+
+def _pixel_rows(bev: ev.BlockEvents) -> ev.BlockEvents:
+    """A strip's events as one event row per pixel: (G, E, 8, bk) tiles
+    become (G·8, E, 1, bk), each row keeping its strip's slots.  XLA:CPU
+    sums a one-row dot's K products in another order than an eight-row
+    dot's when N is narrow (a matrix-vector kernel against a matrix-matrix
+    one), so contracting per pixel, as the per-tap path does, keeps the two
+    bitwise equal on every host."""
+    g, e, bm, bk = bev.values.shape
+    return ev.BlockEvents(
+        values=bev.values.transpose(0, 2, 1, 3).reshape(g * bm, e, 1, bk),
+        block_idx=jnp.repeat(bev.block_idx, bm, axis=0),
+        counts=jnp.repeat(bev.counts, bm), num_k_blocks=bev.num_k_blocks)

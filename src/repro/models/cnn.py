@@ -274,7 +274,8 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
     (``densify`` — dense-pool fallbacks plus re-tile-ineligible FC seams;
     0 when every boundary is eligible, the DESIGN.md §7/§12 invariant
     serving and benchmarks report).  ``routes`` lists, in chain order, the
-    routing decision of every boundary that consumes an EventStream — the
+    routing decision of every boundary that consumes an EventStream, with
+    its ``layer`` index in ``spec.layers`` — the
     same ``engine.route_conv`` / ``engine.route_pool`` /
     ``engine.route_linear`` calls the dispatch makes (DESIGN.md §11), so
     serving's boundary report can state each compiled boundary's route
@@ -313,7 +314,8 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
                     conv_base, stride=layer.stride, padding=layer.padding,
                     blk_m=blk_m)
                 out["routes"].append(dict(
-                    op="conv2d", route=dec.route, occupancy=dec.occupancy,
+                    op="conv2d", layer=i, route=dec.route,
+                    occupancy=dec.occupancy,
                     est_event_cost=dec.est_event_cost,
                     est_dense_cost=dec.est_dense_cost, source=dec.source,
                     shape_class=f"k{layer.k}s{layer.stride}"))
@@ -331,7 +333,7 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
                         (batch, h, w, c), blk_m, conv_base.for_conv(c).blk_k)
                 dec = engine.route_linear(batch, kf, layer.out, cfg,
                                           eligible=reason is None)
-                rec = dict(op="linear", route=dec.route,
+                rec = dict(op="linear", layer=i, route=dec.route,
                            occupancy=dec.occupancy,
                            est_event_cost=dec.est_event_cost,
                            est_dense_cost=dec.est_dense_cost,
@@ -357,7 +359,7 @@ def chain_boundary_summary(spec: CNNSpec, *, batch: int = 1,
                                         layer.stride, conv_base,
                                         blk_m=blk_m)
                 out["routes"].append(dict(
-                    op="maxpool2d", route=dec.route,
+                    op="maxpool2d", layer=i, route=dec.route,
                     occupancy=dec.occupancy,
                     est_event_cost=dec.est_event_cost,
                     est_dense_cost=dec.est_dense_cost, source=dec.source,

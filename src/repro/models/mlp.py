@@ -112,10 +112,11 @@ def mlp_boundary_summary(spec: MLPSpec, *, batch: int = 1,
     cfg = _mlp_cfg(engine_cfg, mnf=True, fire_cfg=fire_cfg)
     out = dict(conv=0, fc=len(spec.widths), pool=0, pool_events=0,
                densify=0, input_encode=0, retile=0, routes=[])
-    for fan_in, width in list(zip(spec.feature_sizes(), spec.widths))[1:]:
+    for i, (fan_in, width) in list(enumerate(zip(spec.feature_sizes(),
+                                                 spec.widths)))[1:]:
         dec = engine.route_linear(batch, fan_in, width, cfg)
         out["routes"].append(dict(
-            op="linear", route=dec.route, occupancy=dec.occupancy,
+            op="linear", layer=i, route=dec.route, occupancy=dec.occupancy,
             est_event_cost=dec.est_event_cost,
             est_dense_cost=dec.est_dense_cost, source=dec.source,
             shape_class=engine.linear_shape_class(batch, fan_in, width)))

@@ -224,7 +224,8 @@ class ServeEngine:
     def boundary_report(self, bucket: int | None = None) -> dict:
         """Abstract-trace one bucket's pipeline: chained/pool/fallback
         counts plus the per-boundary routing decisions (no numeric work —
-        ``jax.eval_shape`` under the dispatch tracer).
+        ``jax.eval_shape`` under the dispatch tracer); each route names
+        its ``layer``, the index of the layer scope it was traced under.
         ``fallback_decodes`` must be 0 on an eligible network, and because
         routes are trace-time static (DESIGN.md §11) ``routes`` states
         exactly what each compiled boundary does — a snapshot-restored
@@ -240,7 +241,8 @@ class ServeEngine:
                        engine_cfg=self.engine_cfg)
         with mnf_engine.trace_dispatch() as recs:
             jax.eval_shape(fwd, plan.arg_specs[0], plan.arg_specs[1])
-        routes = [dict(op=r.get("op"), route=r.get("route"),
+        routes = [dict(op=r.get("op"), layer=r.get("layer"),
+                       route=r.get("route"),
                        occupancy=r.get("occupancy"),
                        source=r.get("route_source"),
                        shape_class=r.get("shape_class"))

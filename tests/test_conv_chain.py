@@ -12,6 +12,8 @@ from repro.models.cnn import (ALEXNET, ALEXNET_FF, VGG16, CNNSpec, ConvSpec,
                               FCSpec, PoolSpec, chain_boundary_summary,
                               cnn_forward, init_cnn_params, make_cnn_forward,
                               make_cnn_pipeline)
+from repro.models.mlp import (LENET_300_100, MLPSpec, init_mlp_params,
+                              make_mlp_forward)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -367,3 +369,74 @@ def test_alexnet224_dispatch_grid_steps_match_step_table():
         ("linear", 128, 1 * 32 * 32),                # fc7
         ("linear", 128, 1 * 8 * 32),                 # fc8
     ]
+
+
+#: (layer, op, route, blk_k, grid_steps) of every boundary one abstract
+#: trace of the chained forward dispatches, default EngineConfig, at the
+#: bucket of each benchmark cell.  VGG16@224's conv1_1 is its strip-encoded
+#: 3-channel input (K block 3, padded to an 8-row weight tile inside the
+#: fused kernel, same grid); pool1/pool2 ride the window-major pool, the
+#: 28- and 14-wide convs the per-tap pixel path.  AlexNet's conv1 takes a
+#: dense input and writes no record.
+PINNED_RECORDS = {
+    "vgg16_224-b1": (VGG16, 1, [
+        (0, "conv2d", "strip", 3, 94080),
+        (1, "conv2d", "strip", 64, 94080),
+        (2, "maxpool2d", "window", 64, 12544),
+        (3, "conv2d", "strip", 64, 23520),
+        (4, "conv2d", "strip", 128, 23520),
+        (5, "maxpool2d", "window", 128, 3136),
+        (6, "conv2d", "strip", 128, 11760),
+        (7, "conv2d", "strip", 128, 23520),
+        (8, "conv2d", "strip", 128, 23520),
+        (9, "maxpool2d", "pixel", 128, 6272),
+        (10, "conv2d", "pixel", 128, 9 * 784 * 4 * 2),
+        (11, "conv2d", "pixel", 128, 9 * 784 * 4 * 4),
+        (12, "conv2d", "pixel", 128, 9 * 784 * 4 * 4),
+        (13, "maxpool2d", "pixel", 128, 3136),
+        (14, "conv2d", "pixel", 128, 9 * 196 * 4 * 4),
+        (15, "conv2d", "pixel", 128, 9 * 196 * 4 * 4),
+        (16, "conv2d", "pixel", 128, 9 * 196 * 4 * 4),
+        (17, "maxpool2d", "pixel", 128, 784),
+        (18, "linear", "event", 128, 32 * 196),
+        (19, "linear", "event", 128, 32 * 32),
+        (20, "linear", "event", 128, 8 * 32),
+    ]),
+    # The first FC takes the dense input and writes no record.
+    "lenet300-b128": (LENET_300_100, 128, [
+        (1, "linear", "event", 128, 16 * 3),
+        (2, "linear", "event", 100, 16 * 1),
+    ]),
+    "alexnet224-b1": (ALEXNET, 1, [
+        (1, "maxpool2d", "pixel", 96, 27 * 27 * 9 * 1),
+        (2, "conv2d", "pixel", 96, 25 * 729 * 2 * 1),
+        (3, "maxpool2d", "pixel", 128, 13 * 13 * 9 * 2),
+        (4, "conv2d", "pixel", 128, 9 * 169 * 3 * 2),
+        (5, "conv2d", "pixel", 128, 9 * 169 * 3 * 3),
+        (6, "conv2d", "pixel", 128, 9 * 169 * 2 * 3),
+        (7, "maxpool2d", "pixel", 128, 6 * 6 * 9 * 2),
+        (8, "linear", "event", 128, 32 * 72),
+        (9, "linear", "event", 128, 32 * 32),
+        (10, "linear", "event", 128, 8 * 32),
+    ]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED_RECORDS))
+def test_dispatch_records_pinned(cell):
+    """Route, K block, grid steps and layer of every record, per cell.
+    VGG16@224 at b1 walks 694 336 grid steps a frame."""
+    spec, batch, want = PINNED_RECORDS[cell]
+    if isinstance(spec, MLPSpec):
+        params = jax.eval_shape(lambda: init_mlp_params(KEY, spec, 0.5))
+        x = jax.ShapeDtypeStruct((batch, spec.in_features), jnp.float32)
+        with engine.trace_dispatch() as recs:
+            jax.eval_shape(make_mlp_forward(
+                spec, engine_cfg=engine.EngineConfig()), params, x)
+    else:
+        recs = _dispatch_records(spec, batch, engine.EngineConfig())
+    got = [(r["layer"], r["op"], r["route"], r["blk_k"], r["grid_steps"])
+           for r in recs if r.get("op") in ("conv2d", "maxpool2d", "linear")]
+    assert got == want
+    if spec is VGG16:
+        assert sum(g[-1] for g in got) == 694336

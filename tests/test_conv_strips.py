@@ -80,6 +80,31 @@ def test_strip_bitwise_equals_pertap_and_oracle(backend, shape):
                                atol=2e-4, rtol=2e-4)
 
 
+@pytest.mark.parametrize("backend", ["block", "pallas"])
+@pytest.mark.parametrize("co", [16, 64])
+def test_padded_strip_head_bitwise_equals_pertap_oracle(backend, co):
+    """The chain head of VGG16: a dense 3-channel image strip-encoded at
+    the chain's blk_k tiles K at 3, and the fused kernel pads each K block
+    to an 8-row weight tile (``lane_blk_k``) whose zero rows never enter
+    the dot — bit-identical to the per-tap dispatch of the dense input."""
+    from repro.kernels.event_conv.kernel import lane_blk_k
+    x = jnp.asarray(np.random.default_rng(co).random((2, 16, 16, 3),
+                                                     np.float32))
+    wgt = jnp.asarray(np.random.default_rng(1).normal(
+        size=(3, 3, 3, co)).astype(np.float32))
+    cfg = engine.EngineConfig(backend=backend, blk_m=1)
+    head = engine.EventStream.encode_nhwc(x, blk_k=cfg.blk_k,
+                                          blk_m=engine.STRIP_W,
+                                          keep_dense=False)
+    assert head.blk_k == 3 and lane_blk_k(head.blk_k) == 8
+    ccfg = cfg.for_conv(3)
+    with engine.trace_dispatch() as recs:
+        y_strip = engine.conv2d(head, wgt, cfg=ccfg, padding=1)
+    assert [(r["route"], r["blk_k"]) for r in recs] == [("strip", 3)]
+    y_tap = engine.conv2d(x, wgt, cfg=ccfg, padding=1)
+    assert bool(jnp.all(y_strip.reshape(y_tap.shape) == y_tap))
+
+
 # ---------------------------------------------------------------------------
 # eligibility rules + EngineConfig.for_conv strip selection/validation
 # ---------------------------------------------------------------------------

@@ -194,6 +194,20 @@ def test_boundary_report_no_fallback(served):
         assert rep["chained"] >= 1 and rep["pool_events"] == 1
 
 
+def test_boundary_report_routes_name_their_layer(served):
+    """Each route of the report names the layer scope it was traced under:
+    the same layers, in the same order, as the planner's routes."""
+    from repro.models.cnn import chain_boundary_summary
+    spec, _, eng, _ = served
+    for bucket in BUCKETS:
+        got = [(r["op"], r["layer"])
+               for r in eng.boundary_report(bucket)["routes"]]
+        planned = chain_boundary_summary(spec, batch=bucket,
+                                         engine_cfg=eng.engine_cfg)["routes"]
+        assert got == [(r["op"], r["layer"]) for r in planned]
+        assert [layer for _, layer in got] == sorted({l for _, l in got})
+
+
 def test_executable_snapshot_restore(served, tmp_path):
     """A restarted replica restores finished executables from cache_dir —
     zero recompiles, bitwise-identical logits."""

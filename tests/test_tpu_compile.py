@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for a v5e chip at AlexNet@224 widths.
+"""The main path's Pallas kernels compile for a v5e chip at the served
+AlexNet@224 and VGG16@224 widths.
 
 Interpret mode accepts block shapes and scalar-prefetch tables that the
 chip's compiler refuses (tiling alignment, the 1 MiB SMEM), so these
@@ -15,8 +16,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import events as ev
+from repro.engine import EventStream
+from repro.kernels.event_conv import fused_event_conv2d
 from repro.kernels.event_matmul.kernel import event_matmul_pallas
-from repro.kernels.event_pool.kernel import event_pool_pallas
+from repro.kernels.event_pool.kernel import (event_pool_pallas,
+                                             event_pool_window_pallas)
 from repro.kernels.fire_compact.kernel import fire_compact_pallas
 from repro.kernels.smem import SMEM_PLAN_WORDS, plan_chunks
 
@@ -158,6 +162,67 @@ def test_event_matmul_fc6(one_chip):
         fc, one_chip, ((BATCH, e, 1, 128), jnp.float32),
         ((BATCH, e), jnp.int32), ((BATCH,), jnp.int32),
         ((9216, 4096), jnp.float32))
+
+
+@pytest.mark.parametrize("ci", [3, 64], ids=["conv1_1", "conv1_2"])
+def test_event_conv_fused_vgg16_224(one_chip, ci):
+    """VGG16's first two convs at b1: a 224x224 strip stream (6272 strips
+    of 8 pixels, one K block a strip) into 64 channels through the fused
+    strip kernel.  conv1_1's stream is the 3-channel input image, whose
+    3-wide K block the kernel pads to an 8-row weight tile."""
+    shape = (1, 224, 224, ci)
+    g = 224 * 224 // ev.STRIP_W
+
+    def conv(vals, idx, cnt, w):
+        stream = EventStream(
+            events=ev.BlockEvents(vals, idx, cnt, num_k_blocks=1),
+            fired=None, shape=(224 * 224, ci), blk_m=ev.STRIP_W, blk_k=ci,
+            logical_shape=shape)
+        return fused_event_conv2d(stream, w, padding=1, blk_n=64,
+                                  interpret=False)
+
+    assert "tpu_custom_call" in _compile(
+        conv, one_chip, ((g, 1, ev.STRIP_W, ci), jnp.float32),
+        ((g, 1), jnp.int32), ((g,), jnp.int32), ((3, 3, ci, 64), jnp.float32))
+
+
+def test_event_pool_window_vgg16_pool1(one_chip):
+    """VGG16's pool1 at b1: 2x2/2 window-major over conv1_2's 224x224x64
+    strip stream, one 64-wide K block a strip."""
+    shape = (1, 224, 224, 64)
+    src, _, shift, _ = ev.pool_strip_map(shape, 2, 2)
+    g = 224 * 224 // ev.STRIP_W
+
+    def pool(vals, idx, cnt):
+        return event_pool_window_pallas(vals, idx, shift, src, cnt, nkb=1,
+                                        row_stride=2, interpret=False)
+
+    assert "tpu_custom_call" in _compile(
+        pool, one_chip, ((g, 1, ev.STRIP_W, 64), jnp.float32),
+        ((g, 1), jnp.int32), (src.shape, jnp.int32))
+
+
+def test_vgg16_224_forward_b1(one_chip):
+    """The whole chained VGG16@224 forward at b1 on the Pallas kernels:
+    strip convs (the padded 3-channel head among them), window-major and
+    per-event pools, per-tap pixel convs and the re-tiled FC head."""
+    from repro import engine
+    from repro.models.cnn import VGG16, init_cnn_params, make_cnn_forward
+    cfg = engine.EngineConfig(backend="pallas", interpret=False)
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.PRNGKey(0),
+                                                    VGG16))
+    specs = [(p.shape, p.dtype) for p in jax.tree.leaves(params)]
+    treedef = jax.tree.structure(params)
+
+    def fwd(x, *leaves):
+        return make_cnn_forward(VGG16, engine_cfg=cfg)(
+            jax.tree.unflatten(treedef, leaves), x)
+
+    with engine.trace_dispatch() as recs:
+        text = _compile(fwd, one_chip, ((1, 224, 224, 3), jnp.float32),
+                        *specs)
+    assert "mnf_event_conv_fused" in text and "mnf_event_pool_window" in text
+    assert not any(r.get("fallback_decode") for r in recs)
 
 
 @pytest.mark.parametrize("n_rows,row_words,src_words", [
